@@ -204,12 +204,16 @@ def _transformer(spec: ModelSpec):
     from repro.models import transformer as tf
     bundle = get_config(spec.arch)
     cfg = bundle.smoke if spec.smoke else bundle.model
+    # published widths checkpoint every block as their ParallelConfig says
+    # (without it K full-width agents' activations overflow one chip); the
+    # 2-layer smoke configs keep every activation
+    remat = bundle.parallel.remat and not spec.smoke
 
     def loss(p, b):
-        return tf.train_loss(p, cfg, b, remat=False)
+        return tf.train_loss(p, cfg, b, remat=remat)
 
     def loss_rng(p, b, rng):
-        return tf.train_loss(p, cfg, b, rng, remat=False)
+        return tf.train_loss(p, cfg, b, rng, remat=remat)
 
     return ModelBundle(cfg=cfg, loss=loss, loss_rng=loss_rng,
                        init_params=lambda k: tf.init_params(k, cfg))
@@ -351,7 +355,7 @@ def make_block_provider(spec: ExperimentSpec, cfg):
 # -- the entry point --------------------------------------------------------
 
 def build(spec: ExperimentSpec, loss_fn=None, *, engine: str = "auto",
-          grad_transform=None):
+          grad_transform=None, mesh=None):
     """Materialize an engine from a declarative spec.
 
     Args:
@@ -365,6 +369,11 @@ def build(spec: ExperimentSpec, loss_fn=None, *, engine: str = "auto",
         self-contained).
       grad_transform: explicit gradient-transform override; defaults to the
         optimizer spec ("sgd" means None — exact Algorithm 1).
+      mesh: a device mesh with a ``"data"`` axis to shard the agent axis
+        over (sharded engine only; see :func:`repro.launch.mesh.
+        make_agent_mesh`).  It also informs the "auto" mixer: across
+        several devices the combination step is a collective, never the
+        single-device Pallas kernel.
 
     Returns:
       A :class:`~repro.core.diffusion.DiffusionEngine` or
@@ -382,7 +391,11 @@ def build(spec: ExperimentSpec, loss_fn=None, *, engine: str = "auto",
              if topology is not None else None)
     # "auto" must not pick the sparse path for graphs that realize edges
     # outside the base support; resolve before the registry lookup
-    mix_kind = graph_lib.resolve_mix_for_graph(spec.mixer.kind, graph)
+    mix_kind = graph_lib.resolve_mix_for_graph(spec.mixer.kind, graph, mesh)
+    if mix_kind == "auto":
+        # resolved here, where the mesh is known: the registry builders
+        # see only the spec, the topology and K
+        mix_kind, _ = mixing.resolve_auto(topology, mesh=mesh)
     mixer = MIXERS.get(mix_kind)(spec.mixer, topology, K)
     graph_lib.check_mixer_support(mixer, graph)
     compressor = COMPRESSORS.get(spec.compression.kind)(spec.compression)
@@ -438,6 +451,9 @@ def build(spec: ExperimentSpec, loss_fn=None, *, engine: str = "auto",
     if engine not in ("stacked", "sharded", "async"):
         raise ValueError(f"unknown engine {engine!r} "
                          "(expected stacked|sharded|async|auto)")
+    if mesh is not None and engine != "sharded":
+        raise ValueError(f"a device mesh shards the sharded engine's agent "
+                         f"axis; engine={engine!r} has none")
     if spec.compression.ef_host_offload and engine != "sharded":
         # the stacked/async engines have no between-block comm memory to
         # park on the host; silently ignoring the flag would report a
@@ -498,7 +514,9 @@ def build(spec: ExperimentSpec, loss_fn=None, *, engine: str = "auto",
                             participation=process, compress=compressor,
                             graph=graph, grad_transform=grad_transform,
                             privacy=privacy,
-                            ef_host_offload=spec.compression.ef_host_offload)
+                            ef_host_offload=spec.compression.ef_host_offload,
+                            mesh=mesh,
+                            agent_axis="data" if mesh is not None else None)
 
     eng.spec = spec
     eng.optimizer = optimizer

@@ -384,13 +384,16 @@ def make_graph_process(kind: "str | GraphProcess",
                      "kind registered against repro.api.build.GRAPHS)")
 
 
-def resolve_mix_for_graph(mix, graph: GraphProcess | None):
+def resolve_mix_for_graph(mix, graph: GraphProcess | None, mesh=None):
     """The "auto" mixer policy must not pick the sparse circulant path for
     graphs whose realized edges can leave the base support (tv_erdos) —
-    fall back to the always-correct backends instead."""
+    fall back to the always-correct backends instead (the Pallas kernel
+    only where the agent axis sits on one device)."""
     if (isinstance(mix, str) and mix == "auto" and graph is not None
             and not graph.within_base_support):
-        return "pallas" if jax.default_backend() == "tpu" else "dense"
+        from repro.core.mixing import spans_devices
+        return ("pallas" if jax.default_backend() == "tpu"
+                and not spans_devices(mesh) else "dense")
     return mix
 
 

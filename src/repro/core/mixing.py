@@ -45,7 +45,8 @@ Backends differ only in *how* the contraction is executed:
   plain mean, so the robustness tax of the fixed trim disappears.
 
 Use :func:`make_mixer` to construct one; ``"auto"`` picks the Pallas kernel
-on TPU and the sparse path for bounded-degree topologies on other backends.
+on TPU when the agent axis sits on one device, and otherwise the sparse
+path for bounded-degree topologies (a collective-permute across devices).
 Benchmarked head-to-head by ``benchmarks.run bench_mix_backends`` (see
 EXPERIMENTS.md §Perf).
 
@@ -90,6 +91,8 @@ __all__ = [
     "choco_gamma",
     "make_mixer",
     "make_pipeline",
+    "resolve_auto",
+    "spans_devices",
     "mix_dense",
     "mix_sparse",
     "mix_gather",
@@ -281,6 +284,13 @@ class Mixer:
         return f"{type(self).__name__}()"
 
 
+def spans_devices(mesh) -> bool:
+    """Whether ``mesh`` spreads work over more than one device.  A
+    ``pallas_call`` over the whole agent stack is not partitionable, so the
+    kernel backends are chosen only where this is False."""
+    return mesh is not None and mesh.size > 1
+
+
 def _constrain_agent_stack(tree: PyTree, mesh, axis: str) -> PyTree:
     """Pin every leaf's leading (agent) axis to ``axis`` of ``mesh`` via a
     sharding constraint — a no-op spec when the axis size does not divide
@@ -376,9 +386,14 @@ def _round_up(n: int, m: int) -> int:
 class PallasFusedMixer(Mixer):
     """Fused mask+mix Pallas kernel over the flattened parameter pytree.
 
-    The agent-stacked pytree is flattened to one (K, M) float32 buffer padded
-    to a tile multiple; the kernel rebuilds the eq.-20 masked matrix in VMEM
-    per tile and streams the buffer exactly once.  The layout (leaf sizes,
+    The agent-stacked pytree is flattened to one (K, M) buffer padded to a
+    tile multiple — in the leaves' own dtype when they are all bfloat16,
+    float32 otherwise; the kernel rebuilds the eq.-20 masked matrix in VMEM
+    per tile, accumulates in float32, and streams the buffer exactly once.
+    A bfloat16 buffer gives bit-identical results to a float32 one (the
+    upcast is exact and the output is rounded to bfloat16 once either
+    way) at half the HBM footprint and traffic; in a full-width block step
+    these two buffers are the largest live set.  The layout (leaf sizes,
     padding, effective tile) is computed on first use per pytree structure
     and cached, so repeated block steps pay zero layout overhead.
 
@@ -418,17 +433,19 @@ class PallasFusedMixer(Mixer):
 
         leaves, treedef = jax.tree_util.tree_flatten(params)
         lay = self._layout(leaves, treedef)
-        flat = self._flatten(leaves, lay)
+        dtype = (jnp.bfloat16 if all(l.dtype == jnp.bfloat16 for l in leaves)
+                 else jnp.float32)
+        flat = self._flatten(leaves, lay, dtype)
         interpret = (jax.default_backend() != "tpu"
                      if self.interpret is None else self.interpret)
         mixed = diffusion_mix(A_t.astype(jnp.float32), active, flat,
                               tile_m=lay.tile_m, interpret=interpret)
         return self._unflatten(mixed, leaves, treedef, lay)
 
-    def _flatten(self, leaves, lay) -> jax.Array:
+    def _flatten(self, leaves, lay, dtype=jnp.float32) -> jax.Array:
         K = leaves[0].shape[0]
         flat = jnp.concatenate(
-            [l.reshape(K, -1).astype(jnp.float32) for l in leaves], axis=1)
+            [l.reshape(K, -1).astype(dtype) for l in leaves], axis=1)
         if lay.M_padded != lay.M:
             flat = jnp.pad(flat, ((0, 0), (0, lay.M_padded - lay.M)))
         return flat
@@ -490,11 +507,12 @@ class NeighborGatherMixer(Mixer):
     whenever the realized graphs stay ``within_base_support``
     (:func:`repro.core.graphs.check_mixer_support` rejects tv_erdos).
 
-    ``fused=None`` resolves per call: on TPU the fused Pallas gather
-    kernel (:func:`repro.kernels.diffusion_mix.gather_mix`) streams the
-    cached (K, M) flatten layout once (the :class:`PallasFusedMixer`
-    tile/layout cache is reused); elsewhere the per-leaf gather einsum
-    runs.  ``fused=True`` forces the kernel (interpret mode off-TPU);
+    ``fused=None`` resolves per call: on TPU, with the agent axis on one
+    device, the fused Pallas gather kernel
+    (:func:`repro.kernels.diffusion_mix.gather_mix`) streams the cached
+    (K, M) flatten layout once (the :class:`PallasFusedMixer` tile/layout
+    cache is reused); elsewhere the per-leaf gather einsum runs.
+    ``fused=True`` forces the kernel (interpret mode off-TPU);
     ``fused=False`` forces the einsum.
 
     :meth:`shard_agent_axis` pins the (K, ...) stack and the (K, D)
@@ -542,6 +560,7 @@ class NeighborGatherMixer(Mixer):
             params = _constrain_agent_stack(params, self._mesh,
                                             self._agent_axis)
         fused = (jax.default_backend() == "tpu"
+                 and not spans_devices(self._mesh)
                  if self.fused is None else bool(self.fused))
         if fused:
             from repro.kernels.diffusion_mix import gather_mix
@@ -1036,7 +1055,8 @@ class FusedNeighborhoodMixer(Mixer):
 
     def __call__(self, params: PyTree, active: jax.Array,
                  A_t: jax.Array) -> PyTree:
-        use = True if self.use_kernel is None else bool(self.use_kernel)
+        use = (not spans_devices(self._mesh) if self.use_kernel is None
+               else bool(self.use_kernel))
         if not use or self.inner._table is None:
             return self.inner(params, active, A_t)
         from repro.kernels.diffusion_mix import gather_robust_mix
@@ -1457,11 +1477,13 @@ class CommPipeline:
 # factory
 # ---------------------------------------------------------------------------
 
-def _resolve_auto(topology: topo_lib.Topology | None,
-                  offsets: Sequence[int] | None):
+def resolve_auto(topology: topo_lib.Topology | None,
+                 offsets: Sequence[int] | None = None, *, mesh=None):
     """Pick a backend name; returns (name, offsets) so the sparse branch is
-    built with exactly the offsets the decision was based on."""
-    if jax.default_backend() == "tpu":
+    built with exactly the offsets the decision was based on.  The Pallas
+    kernel is picked on TPU unless ``mesh`` spreads the agents over several
+    devices: there the combination step must be a collective."""
+    if jax.default_backend() == "tpu" and not spans_devices(mesh):
         return "pallas", offsets
     if topology is not None and topology.max_degree < topology.num_agents - 1:
         # irregular graphs (e.g. Erdős–Rényi) can have low degree but many
@@ -1483,7 +1505,8 @@ def make_mixer(name: str | Mixer, topology: topo_lib.Topology | None = None,
                *, A=None, offsets: Sequence[int] | None = None,
                num_agents: int | None = None, tile_m: int = 512,
                interpret: bool | None = None, trim: int = 1,
-               scope: str = "global", gather: str = "auto") -> Mixer:
+               scope: str = "global", gather: str = "auto",
+               mesh=None) -> Mixer:
     """Build a mixing backend.
 
     The matrix is NOT baked into the mixer — it arrives per call as the
@@ -1515,6 +1538,8 @@ def make_mixer(name: str | Mixer, topology: topo_lib.Topology | None = None,
         gather kernel, topology required), or "off" (the all-slots sort,
         valid even off base support).  Graph-support validity is enforced
         later by :func:`repro.core.graphs.check_mixer_support`.
+      mesh: the device mesh the agent axis will be sharded over, if any —
+        informs the "auto" policy (see :func:`resolve_auto`).
     """
     if isinstance(name, Mixer):
         return name
@@ -1571,7 +1596,7 @@ def make_mixer(name: str | Mixer, topology: topo_lib.Topology | None = None,
             mixer._gather_mode = "table"
         return mixer
     if name == "auto":
-        name, offsets = _resolve_auto(topology, offsets)
+        name, offsets = resolve_auto(topology, offsets, mesh=mesh)
     if name == "dense":
         return DenseMixer()
     if name == "sparse":

@@ -62,41 +62,38 @@ __all__ = ["mix_dense", "mix_sparse", "make_block_step", "ShardedEngine",
 # ---------------------------------------------------------------------------
 
 def ef_host_sharding():
-    """The host-memory sharding EF-residual offload parks tensors in, or
-    ``None`` when the backend exposes no distinct pinned-host space (CPU:
-    arrays already live in host RAM — offload is an explicit no-op there,
-    gated by the parity test, not a crash)."""
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if "pinned_host" in kinds:
-            return jax.sharding.SingleDeviceSharding(
-                dev, memory_kind="pinned_host")
-    except Exception:
+    """The pinned-host sharding of the default device, or ``None`` when the
+    backend exposes no pinned-host memory space (then the offload is an
+    explicit no-op)."""
+    dev = jax.devices()[0]
+    if "pinned_host" not in {m.kind for m in dev.addressable_memories()}:
         return None
-    return None
+    return jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
+
+
+def _move_comm_state(comm_state: PyTree, memory_kind: str) -> PyTree:
+    """Each leaf to ``memory_kind``, keeping its sharding (one device or an
+    agent mesh)."""
+    if ef_host_sharding() is None or comm_state is None or comm_state == ():
+        return comm_state
+    return jax.tree.map(
+        lambda l: jax.device_put(l, l.sharding.with_memory_kind(memory_kind)),
+        comm_state)
 
 
 def offload_comm_state(comm_state: PyTree) -> PyTree:
-    """Move the pipeline memory (EF residual / diff reference) to host
-    memory between blocks — frees ~1x params of HBM while the model's
-    forward/backward owns the device.  ``may_alias`` lets the runtime
-    reuse an existing host copy instead of forcing a fresh transfer."""
-    host = ef_host_sharding()
-    if host is None or comm_state is None or comm_state == ():
-        return comm_state
-    return jax.tree.map(
-        lambda l: jax.device_put(l, host, may_alias=True), comm_state)
+    """Move the pipeline memory (EF residual / diff reference) to pinned
+    host memory between blocks — frees ~1x params of HBM while the
+    model's forward/backward owns the device."""
+    return _move_comm_state(comm_state, "pinned_host")
 
 
 def fetch_comm_state(comm_state: PyTree) -> PyTree:
     """Bring an offloaded pipeline memory back to the default device
-    memory ahead of the next block's combination step."""
-    if ef_host_sharding() is None or comm_state is None or comm_state == ():
-        return comm_state
-    dev = jax.devices()[0]
-    return jax.tree.map(
-        lambda l: jax.device_put(l, dev, may_alias=True), comm_state)
+    memory ahead of the next block's combination step.  The target names
+    the ``device`` memory kind explicitly: a sharding without one keeps
+    the buffer's own (host) kind."""
+    return _move_comm_state(comm_state, "device")
 
 
 def make_block_step(
@@ -168,11 +165,13 @@ def make_block_step(
       comm_mode / comm_gamma: exchange scheme and consensus step of the
         :class:`repro.core.mixing.CommPipeline` (defaults: config fields;
         "auto" picks diff mode for sparsifiers, direct for int8).
-      mesh / agent_axis: agent-axis sharding for the scale path — when a
-        mesh is given, mixers that materialize the (K, M) stack pin its
-        agent rows to ``agent_axis`` (default "data") via
-        :func:`repro.sharding.rules.agent_stack_pspec`, and the generic
-        int8 pipeline keeps the quantized bytes on the wire under GSPMD.
+      mesh / agent_axis: agent-axis sharding — when a mesh is given,
+        mixers that materialize the (K, M) stack pin its agent rows to
+        ``agent_axis`` (default "data") via
+        :func:`repro.sharding.rules.agent_stack_pspec`, the generic int8
+        pipeline keeps the quantized bytes on the wire under GSPMD, and
+        "auto" picks a collective backend when the mesh spans several
+        devices (:func:`repro.core.mixing.resolve_auto`).
       privacy: compiled :class:`repro.core.privacy.Privacy` tier or None —
         advances the RDP accountant in ``EngineState.privacy_state`` at
         the realized participation rate every block (scaled by the T
@@ -205,7 +204,8 @@ def make_block_step(
                               offsets=tuple(offsets) or None,
                               num_agents=K, tile_m=tile_m,
                               interpret=interpret, trim=trim,
-                              scope=robust_scope, gather=robust_gather)
+                              scope=robust_scope, gather=robust_gather,
+                              mesh=mesh)
     A_graph = A
     if topology is None and A is None and not mixer.uses_matrix:
         # mixers that ignore the matrix operand (K = 1 / robust server
@@ -215,7 +215,7 @@ def make_block_step(
     graph_proc = graph_lib.make_graph_process(
         graph if graph is not None else config.graph, topology, A=A_graph,
         num_agents=K, **dict(config.graph_kwargs))
-    resolved = graph_lib.resolve_mix_for_graph(mix_name, graph_proc)
+    resolved = graph_lib.resolve_mix_for_graph(mix_name, graph_proc, mesh)
     if resolved is not mix_name:
         # "auto" picked the sparse path before the graph was known; the
         # realized edges can leave the base support, so rebuild on the
@@ -223,7 +223,7 @@ def make_block_step(
         mixer = mixing.make_mixer(resolved, topology, A=A, num_agents=K,
                                   tile_m=tile_m, interpret=interpret,
                                   trim=trim, scope=robust_scope,
-                                  gather=robust_gather)
+                                  gather=robust_gather, mesh=mesh)
     graph_lib.check_mixer_support(mixer, graph_proc)
     if mesh is not None:
         mixer.shard_agent_axis(mesh, agent_axis or "data")

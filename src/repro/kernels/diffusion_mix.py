@@ -36,13 +36,15 @@ Four variants:
   weights (trimmed mean / median) — the fused gather + trim + mix of the
   O(K dmax M log dmax) neighborhood path.
 
-The gather kernels take the index table as a *scalar-prefetch* operand
-(``pltpu.PrefetchScalarGridSpec``), the supported TPU pattern for
-data-dependent row addressing: the indices land in SMEM before the body
-runs and feed ``pl.ds`` dynamic slices of the (K, tile_m) parameter block.
-The grid is (num_tiles, K) with K innermost, so the parameter tile stays
-resident in VMEM across the whole K sweep and only the tiny per-row
-operands change between programs.
+The gather kernels take the index table and every per-row operand as
+*scalar-prefetch* operands (``pltpu.PrefetchScalarGridSpec``), the
+supported TPU pattern for data-dependent row addressing: they land in SMEM,
+flattened to 1-D, before the body runs, and the indices feed ``pl.ds``
+dynamic slices of the (K, tile_m) parameter block.  The grid is
+(num_tiles, K / R) with the row blocks innermost, so the parameter tile
+stays resident in VMEM across the whole K sweep; each program writes an
+(R, tile_m) output block, R = 8 rows (or all K when 8 does not divide K),
+the smallest block the TPU lowering accepts.
 """
 from __future__ import annotations
 
@@ -52,6 +54,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+# per-tile int8 scales travel in lane-dense (K, 128) blocks: a (K, 1) block
+# per tile is refused by the TPU lowering (last block dim must be a multiple
+# of 128 or the whole array)
+_SCALE_LANES = 128
 
 
 def _masked_matrix(A: jax.Array, m: jax.Array, K: int,
@@ -70,6 +78,14 @@ def _masked_matrix(A: jax.Array, m: jax.Array, K: int,
     return A_eff
 
 
+def _contract(A_eff: jax.Array, W: jax.Array) -> jax.Array:
+    """A_eff^T @ W in full float32.  HIGHEST pins the f32 contraction on
+    the MXU instead of leaving its pass count to the compiler default."""
+    return jax.lax.dot_general(A_eff, W, (((0,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
 def _mix_kernel(a_ref, m_ref, w_ref, o_ref, *, K: int):
     A = a_ref[...].astype(jnp.float32)                  # (K, K)
     m = m_ref[...].astype(jnp.float32)[:, 0]            # (K,)
@@ -77,22 +93,22 @@ def _mix_kernel(a_ref, m_ref, w_ref, o_ref, *, K: int):
     A_eff = _masked_matrix(A, m, K)
 
     # W'_k = sum_l A_eff[l, k] W[l]  ==  A_eff^T @ W
-    out = jax.lax.dot_general(A_eff, W, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    o_ref[...] = out.astype(o_ref.dtype)
+    o_ref[...] = _contract(A_eff, W).astype(o_ref.dtype)
 
 
 def _mix_int8_kernel(a_ref, m_ref, wq_ref, s_ref, o_ref, *, K: int,
                      subtract_identity: bool):
     A = a_ref[...].astype(jnp.float32)                  # (K, K)
     m = m_ref[...].astype(jnp.float32)[:, 0]            # (K,)
-    scale = s_ref[...].astype(jnp.float32)              # (K, 1) per-tile
+    # the scale block holds _SCALE_LANES tiles' scales; select this tile's
+    # column (one nonzero term per row, so the sum is exact)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (K, _SCALE_LANES), 1)
+    col = pl.program_id(0) % _SCALE_LANES
+    scale = jnp.sum(jnp.where(lane == col, s_ref[...], 0.0), axis=1,
+                    keepdims=True)                      # (K, 1) per-tile
     W = wq_ref[...].astype(jnp.float32) * scale         # dequantize in VMEM
     A_eff = _masked_matrix(A, m, K, subtract_identity=subtract_identity)
-
-    out = jax.lax.dot_general(A_eff, W, (((0,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    o_ref[...] = out.astype(o_ref.dtype)
+    o_ref[...] = _contract(A_eff, W).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
@@ -156,6 +172,8 @@ def diffusion_mix_int8(A: jax.Array, active: jax.Array, Wq: jax.Array,
         raise ValueError(f"scales shape {scales.shape} != ({K}, {nm})")
     kernel = functools.partial(_mix_int8_kernel, K=K,
                                subtract_identity=subtract_identity)
+    pad = (-nm) % _SCALE_LANES
+    scales = jnp.pad(scales.astype(jnp.float32), ((0, 0), (0, pad)))
     return pl.pallas_call(
         kernel,
         grid=(nm,),
@@ -163,12 +181,13 @@ def diffusion_mix_int8(A: jax.Array, active: jax.Array, Wq: jax.Array,
             pl.BlockSpec((K, K), lambda mi: (0, 0)),
             pl.BlockSpec((K, 1), lambda mi: (0, 0)),
             pl.BlockSpec((K, tile_m), lambda mi: (0, mi)),
-            pl.BlockSpec((K, 1), lambda mi: (0, mi)),
+            pl.BlockSpec((K, _SCALE_LANES),
+                         lambda mi: (0, mi // _SCALE_LANES)),
         ],
         out_specs=pl.BlockSpec((K, tile_m), lambda mi: (0, mi)),
         out_shape=jax.ShapeDtypeStruct((K, M), jnp.float32),
         interpret=interpret,
-    )(A, active.reshape(K, 1), Wq, scales.astype(jnp.float32))
+    )(A, active.reshape(K, 1), Wq, scales)
 
 
 # ---------------------------------------------------------------------------
@@ -206,39 +225,77 @@ def _bitonic_sort(rows: list) -> list:
     return rows
 
 
-def _gather_rows(idx_ref, w_ref, k: int, D: int) -> list:
-    """The D contributor rows of target k, via SMEM-prefetched indices."""
-    return [w_ref[pl.ds(idx_ref[k, j], 1), :] for j in range(D)]
+def _row_block(K: int) -> int:
+    """Target rows per output block: the TPU lowering wants blocks of at
+    least 8 rows (or the whole agent axis)."""
+    return 8 if K % 8 == 0 else K
 
 
-def _gather_mix_kernel(idx_ref, gw_ref, w_ref, o_ref, *, D: int):
-    k = pl.program_id(1)
-    rows = _gather_rows(idx_ref, w_ref, k, D)
-    acc = gw_ref[0, 0] * rows[0]
-    for j in range(1, D):
-        acc = acc + gw_ref[0, j] * rows[j]
-    o_ref[...] = acc
+def _gather_rows(idx_ref, w_ref, k, D: int) -> list:
+    """The D contributor rows of target k, via SMEM-prefetched indices
+    (the (K, D) table arrives flattened row-major)."""
+    return [w_ref[pl.ds(idx_ref[k * D + j], 1), :] for j in range(D)]
+
+
+def _for_each_row(R: int, body) -> None:
+    """Run ``body(r, k)`` for the R target rows k of this output block."""
+    k0 = pl.program_id(1) * R
+
+    def step(r, carry):
+        body(r, k0 + r)
+        return carry
+
+    jax.lax.fori_loop(0, R, step, 0)
+
+
+def _gather_mix_kernel(idx_ref, gw_ref, w_ref, o_ref, *, D: int, R: int):
+    def row(r, k):
+        rows = _gather_rows(idx_ref, w_ref, k, D)
+        acc = gw_ref[k * D] * rows[0]
+        for j in range(1, D):
+            acc = acc + gw_ref[k * D + j] * rows[j]
+        o_ref[pl.ds(r, 1), :] = acc
+
+    _for_each_row(R, row)
 
 
 def _gather_robust_kernel(idx_ref, mem_ref, ws_ref, act_ref, w_ref, o_ref, *,
-                          D: int):
-    k = pl.program_id(1)
-    rows = _gather_rows(idx_ref, w_ref, k, D)
-    own = rows[0]                                     # slot 0 is self
-    # non-members (and padding slots) to +inf so the S_k live values
-    # occupy the first S_k ascending slots, exactly like the all-slots sort
-    vals = [jnp.where(mem_ref[0, j] > 0, rows[j], jnp.inf) for j in range(D)]
+                          D: int, R: int):
     P = _next_pow2(D)
-    vals += [jnp.full_like(own, jnp.inf)] * (P - D)
-    srt = _bitonic_sort(vals)
-    # weights are zero on every slot >= S_k (those hold +inf); the where
-    # keeps 0 * inf = nan out of the contraction
-    acc = jnp.zeros_like(own)
-    for j in range(D):                                # slots >= D unweighted
-        wj = ws_ref[0, j]
-        acc = acc + jnp.where(wj > 0, srt[j], 0.0) * wj
-    # inactive targets keep their own row exactly (eq.-20 invariant)
-    o_ref[...] = jnp.where(act_ref[0, 0] > 0, acc, own)
+
+    def row(r, k):
+        rows = _gather_rows(idx_ref, w_ref, k, D)
+        own = rows[0]                                 # slot 0 is self
+        # non-members (and padding slots) to +inf so the S_k live values
+        # occupy the first S_k ascending slots, like the all-slots sort
+        vals = [jnp.where(mem_ref[k * D + j] > 0, rows[j], jnp.inf)
+                for j in range(D)]
+        vals += [jnp.full_like(own, jnp.inf)] * (P - D)
+        srt = _bitonic_sort(vals)
+        # weights are zero on every slot >= S_k (those hold +inf); the
+        # where keeps 0 * inf = nan out of the contraction
+        acc = jnp.zeros_like(own)
+        for j in range(D):                            # slots >= D unweighted
+            wj = ws_ref[k * D + j]
+            acc = acc + jnp.where(wj > 0, srt[j], 0.0) * wj
+        # inactive targets keep their own row exactly (eq.-20 invariant)
+        o_ref[pl.ds(r, 1), :] = jnp.where(act_ref[k] > 0, acc, own)
+
+    _for_each_row(R, row)
+
+
+def _gather_grid(K: int, nm: int, R: int, tile_m: int, *,
+                 num_scalar_prefetch: int):
+    """Grid of the gather kernels: (tiles, row blocks) with the row blocks
+    innermost, so the (K, tile_m) parameter tile stays resident in VMEM
+    across the sweep.  The per-row operands (index table, weights, masks)
+    are scalar-prefetched into SMEM, flattened to 1-D."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=num_scalar_prefetch,
+        grid=(nm, K // R),
+        in_specs=[pl.BlockSpec((K, tile_m), lambda mi, kb, *_: (0, mi))],
+        out_specs=pl.BlockSpec((R, tile_m), lambda mi, kb, *_: (kb, mi)),
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
@@ -259,21 +316,14 @@ def gather_mix(idx: jax.Array, gw: jax.Array, W: jax.Array, *,
     if M % tile_m:
         raise ValueError(f"M={M} not divisible by tile_m={tile_m}")
     nm = M // tile_m
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nm, K),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda mi, k, idx_ref: (k, 0)),
-            pl.BlockSpec((K, tile_m), lambda mi, k, idx_ref: (0, mi)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_m), lambda mi, k, idx_ref: (k, mi)),
-    )
+    R = _row_block(K)
     return pl.pallas_call(
-        functools.partial(_gather_mix_kernel, D=D),
-        grid_spec=grid_spec,
+        functools.partial(_gather_mix_kernel, D=D, R=R),
+        grid_spec=_gather_grid(K, nm, R, tile_m, num_scalar_prefetch=2),
         out_shape=jax.ShapeDtypeStruct((K, M), jnp.float32),
         interpret=interpret,
-    )(idx, gw.astype(jnp.float32), W.astype(jnp.float32))
+    )(idx.reshape(-1), gw.astype(jnp.float32).reshape(-1),
+      W.astype(jnp.float32))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
@@ -300,21 +350,12 @@ def gather_robust_mix(idx: jax.Array, member: jax.Array, wslot: jax.Array,
     if M % tile_m:
         raise ValueError(f"M={M} not divisible by tile_m={tile_m}")
     nm = M // tile_m
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nm, K),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda mi, k, idx_ref: (k, 0)),
-            pl.BlockSpec((1, D), lambda mi, k, idx_ref: (k, 0)),
-            pl.BlockSpec((1, 1), lambda mi, k, idx_ref: (k, 0)),
-            pl.BlockSpec((K, tile_m), lambda mi, k, idx_ref: (0, mi)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_m), lambda mi, k, idx_ref: (k, mi)),
-    )
+    R = _row_block(K)
     return pl.pallas_call(
-        functools.partial(_gather_robust_kernel, D=D),
-        grid_spec=grid_spec,
+        functools.partial(_gather_robust_kernel, D=D, R=R),
+        grid_spec=_gather_grid(K, nm, R, tile_m, num_scalar_prefetch=4),
         out_shape=jax.ShapeDtypeStruct((K, M), jnp.float32),
         interpret=interpret,
-    )(idx, member.astype(jnp.float32), wslot.astype(jnp.float32),
-      active.astype(jnp.float32).reshape(K, 1), W.astype(jnp.float32))
+    )(idx.reshape(-1), member.astype(jnp.float32).reshape(-1),
+      wslot.astype(jnp.float32).reshape(-1),
+      active.astype(jnp.float32).reshape(-1), W.astype(jnp.float32))

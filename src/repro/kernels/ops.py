@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) kernels execute in ``interpret=True`` mode for
-correctness validation; on TPU they compile natively.  Each wrapper handles
+On the CPU, which is for tests, kernels execute in ``interpret=True`` mode
+for correctness validation; on TPU they compile natively (run them there
+through ``python chip_smoke.py``).  Each wrapper handles
 padding to block multiples and pytree flattening so callers never see kernel
 layout constraints.
 """

@@ -479,8 +479,6 @@ def dryrun_one(arch: str, shape_name: str, mesh_kind: str,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # newer jax: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = collective_stats(hlo)
     if save_hlo:
@@ -519,13 +517,6 @@ def dryrun_one(arch: str, shape_name: str, mesh_kind: str,
 
 
 def main():
-    # threefry lowering GSPMD can shard: without it the int8 pipeline's
-    # stochastic rounding replicates its f32 input (an f32 all-gather on
-    # the wire) instead of all-gathering the s8 buffer.  The flag changes
-    # the values the RNG emits, so it is scoped to the compile-only CLI
-    # entry point — never set at import time where it would bleed into a
-    # training process that imports this module.
-    jax.config.update("jax_threefry_partitionable", True)
     ap = argparse.ArgumentParser()
     # the spec-mapped flags are the SAME shared set train/serve use
     # (repro/api/cli.py) — drivers cannot drift on names or defaults.

@@ -43,6 +43,7 @@ from repro.configs import get_config
 # consensus_from_stacked moved to repro.core.serving; re-exported here for
 # the existing import surface (tests, notebooks)
 from repro.core.serving import CONSENSUS_QUANTIZE, consensus_from_stacked
+from repro.launch.cache import enable_compile_cache
 from repro.launch.serving import Request, ServeLoop
 from repro.models import transformer as tf
 
@@ -188,6 +189,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     _check_preset_shim(ap, args)
     spec_from_args(args)      # validate the shared flags map onto a spec
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(args.seed)
     kp, kt, key = jax.random.split(key, 3)
@@ -230,14 +232,15 @@ def main(argv=None):
 
     greedy = args.temperature <= 0
     if args.decode_loop == "fused":
-        # params are closed over, not arguments: this process serves ONE
-        # checkpoint, and constant weights let XLA fold/pre-layout them
-        # (measured ~1.6x per decoded token on CPU vs argument weights)
-        fused = jax.jit(lambda c, lg, k: tf.decode_loop(
-            params, cfg, c, lg, k, args.decode,
-            temperature=args.temperature))
+        # params are arguments, as in ServeLoop: closed over, every weight
+        # is serialized into the program as a constant (a 134 MB matrix
+        # made a 268 MB module that took 3.9 s instead of 0.06 s to lower
+        # and compile on the CPU), which at published widths is minutes
+        fused = jax.jit(lambda p, c, lg, k: tf.decode_loop(
+            p, cfg, c, lg, k, args.decode, temperature=args.temperature))
         t0 = time.time()
-        gen, logits, cache = fused(cache, logits, None if greedy else key)
+        gen, logits, cache = fused(params, cache, logits,
+                                   None if greedy else key)
         gen = jax.block_until_ready(gen)
         t_decode = time.time() - t0
     else:

@@ -115,11 +115,9 @@ class ServeLoop:
         self._logits = jnp.zeros(lg_shape, jnp.float32)
         # one jit object per loop; prefill re-specializes per prompt
         # length (cached per shape), decode shapes are fixed.  Params are
-        # ARGUMENTS here, unlike the one-shot serve path which closes over
-        # them: the watch loop hot-swaps checkpoints through the
-        # ParamStore, and argument weights swap with zero recompiles — the
-        # price is the constant-folding speedup the fixed-checkpoint path
-        # gets from baked weights (see EXPERIMENTS.md section Serving)
+        # ARGUMENTS: the watch loop hot-swaps checkpoints through the
+        # ParamStore, and argument weights swap with zero recompiles (see
+        # EXPERIMENTS.md section Serving)
         self._prefill = jax.jit(
             lambda p, t: tf.prefill(p, cfg, t, max_len=max_len))
         self._fused = jax.jit(

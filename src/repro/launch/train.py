@@ -1,9 +1,12 @@
 """End-to-end training driver: diffusion learning (Algorithm 1) over any
 assigned architecture on the local device set.
 
-On CPU this runs the reduced (smoke) configs; on a real TPU mesh it uses the
-same code path with the production mesh.  The experiment is described by ONE
-:class:`repro.api.ExperimentSpec`, built from the shared CLI front end
+On CPU this runs the reduced (smoke) configs; on TPU the same code path runs
+at published widths.  With several devices and K a multiple of their count,
+the agent axis is sharded over them (one 1-D ``data`` mesh, whole agents per
+device) and the combination step becomes a collective.  The experiment is
+described by ONE :class:`repro.api.ExperimentSpec`, built from the shared
+CLI front end
 (:mod:`repro.api.cli` — the same flag set ``dryrun`` and ``serve`` use):
 
   PYTHONPATH=src python -m repro.launch.train --arch smollm-360m --smoke \
@@ -38,6 +41,8 @@ from repro.api import build, spec_from_args
 from repro.api.cli import add_spec_args
 from repro.checkpoint import save_experiment
 from repro.core.privacy import epsilon_from_rdp_np, rdp_increment_np
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_agent_mesh, place_agents
 from repro.models import transformer as tf
 
 
@@ -50,9 +55,14 @@ def main():
     args = ap.parse_args()
 
     spec = spec_from_args(args)
-    eng = build(spec, engine=args.engine)   # transformer model -> sharded
+    enable_compile_cache()
     run = spec.run
     K, T = run.num_agents, run.local_steps
+    sharded = args.engine == "sharded" or (args.engine == "auto"
+                                           and not spec.asynchrony.enabled)
+    mesh = make_agent_mesh(K) if sharded else None
+    # transformer model -> sharded engine
+    eng = build(spec, engine=args.engine, mesh=mesh)
     cfg = eng.model.cfg
     pipeline = getattr(eng, "pipeline", None)   # async: no CommPipeline
     is_async = spec.asynchrony.enabled
@@ -95,6 +105,10 @@ def main():
     opt_state = eng.optimizer.init(params)
     state = eng.init_state(params, opt_state,
                            key=jax.random.fold_in(key, 0x5EED))
+    if mesh is not None:
+        print(f"mesh: {K} agents over {mesh.size} devices "
+              f"({K // mesh.size} per device); mixer {eng.pipeline.mixer.name}")
+        state = place_agents(state, mesh, num_agents=K)
     if spec.compression.kind != "none":
         from repro.core.compression import dense_wire_bytes
         wire = pipeline.wire_bytes(params)
@@ -111,7 +125,9 @@ def main():
                   f"{wire / 1e6:.2f} MB/combination on the wire "
                   f"({dense_wire / wire:.1f}x below dense f32)")
 
-    jit_step = jax.jit(eng.step)
+    # the old state is dead once the step returns: donating it keeps one
+    # copy of the K parameter stacks on the device, not two
+    jit_step = jax.jit(eng.step, donate_argnums=0)
 
     # the data half of the loop is compiled from spec.data by build():
     # provider(block_index, key) — kind="iid" reproduces the legacy
@@ -177,6 +193,8 @@ def main():
                 break
         key, kb, ks = jax.random.split(key, 3)
         batch = sample_block(i, kb)
+        if mesh is not None:
+            batch = place_agents(batch, mesh, num_agents=K, agent_dim=1)
         state, metrics = jit_step(fetch(state), batch, ks)
         state = offload(state)
         blocks_done = i + 1

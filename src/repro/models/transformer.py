@@ -693,7 +693,9 @@ def decode_loop(params: PyTree, cfg: ModelConfig, cache: Cache,
         nxt = sample_logits(lg, ks, temperature)       # (B,) or (B, nq)
         tok = nxt[:, None] if not cfg.num_codebooks else nxt[:, None, :]
         new_lg, c = decode_step(params, cfg, c, tok, window=window)
-        return (new_lg[:, 0], c), nxt
+        # the carry keeps first_logits' dtype (the serve loop holds float32
+        # logits; a bfloat16 model's upcast is exact)
+        return (new_lg[:, 0].astype(lg.dtype), c), nxt
 
     xs = None if greedy else jax.random.split(key, n)
     (last_lg, cache), toks = jax.lax.scan(

@@ -573,3 +573,50 @@ def test_plain_checkpoint_has_no_spec(tmp_path):
     path = str(tmp_path / "plain.npz")
     save_checkpoint(path, {"w": jnp.zeros((2, 2))}, step=1)
     assert load_spec(path) is None
+
+
+# ---------------------------------------------------------------------------
+# build(spec, mesh=): the agent mesh and the published-width model bundle
+# ---------------------------------------------------------------------------
+
+def _lm_spec(smoke=True, K=4):
+    return ExperimentSpec(
+        model=ModelSpec(kind="transformer", arch="smollm-360m", smoke=smoke),
+        mixer=MixerSpec(kind="auto"),
+        run=RunSpec(num_agents=K, local_steps=1, batch=1, seq=16))
+
+
+def test_build_threads_the_mesh_and_picks_a_collective(monkeypatch):
+    from jax.sharding import Mesh
+
+    from repro.core import mixing
+    monkeypatch.setattr(mixing.jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(jax.devices() * 2), ("data",))
+    eng = build(_lm_spec(), mesh=mesh)
+    assert eng.pipeline.mixer.name == "sparse"
+    assert eng.pipeline.mesh is mesh
+    assert eng.pipeline.mixer._mesh is mesh
+    assert eng.pipeline.mixer._agent_axis == "data"
+    assert build(_lm_spec()).pipeline.mixer.name == "pallas"
+    with pytest.raises(ValueError, match="mesh"):
+        build(_lm_spec(), engine="stacked", mesh=mesh)
+
+
+def test_published_widths_rematerialize_every_block():
+    """The full-width bundle follows its ParallelConfig.remat; the smoke
+    bundle keeps every activation."""
+    from repro.api.build import MODELS
+    from repro.api.build import train_block_struct
+
+    def checkpoints(smoke):
+        bundle = MODELS.get("transformer")(
+            ModelSpec(kind="transformer", arch="smollm-360m", smoke=smoke))
+        params = jax.eval_shape(bundle.init_params, jax.random.PRNGKey(0))
+        batch = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape[2:], s.dtype),
+            train_block_struct(bundle.cfg, T=1, K=1, batch=1, seq=16))
+        jaxpr = jax.make_jaxpr(jax.grad(bundle.loss))(params, batch)
+        return "checkpoint" in str(jaxpr) or "remat" in str(jaxpr)
+
+    assert checkpoints(smoke=False)
+    assert not checkpoints(smoke=True)

@@ -112,3 +112,28 @@ def test_serve_window_rules():
     # starcoder2 uses its published 4k window everywhere
     cfg = get_config("starcoder2_15b").model
     assert serve_window(cfg, INPUT_SHAPES["decode_32k"]) == 4096
+
+
+def test_dryrun_compiles_the_production_train_step():
+    """The compile-only dryrun on the 256-device production mesh (forced
+    host devices, in a subprocess): smollm-360m's train step with the int8
+    wire compiles, and its collectives stay near the int8 byte count
+    (EXPERIMENTS.md records 1.52e10 bytes, against 5.94e10 in float32)."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    prog = ("import json, sys\n"
+            "from repro.launch.dryrun import dryrun_one\n"
+            "r = dryrun_one('smollm-360m', 'train_4k', 'single', "
+            "mix_override='dense', compress='int8')\n"
+            "print(json.dumps(r['collectives']['total_bytes']))\n")
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    r = subprocess.run([sys.executable, "-c", prog], cwd=root, env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    total = json.loads(r.stdout.strip().splitlines()[-1])
+    assert 0 < total < 2e10

@@ -298,7 +298,10 @@ def test_sharded_step_threads_graph_state():
                                   key=jax.random.PRNGKey(4))
     assert state.graph_state is not None
     masks = []
-    for i in range(3):
+    # a ring of 6 links keeps its whole mask through one block with
+    # probability ~0.24 at drop=0.3, corr=0.5: over 12 blocks the chance
+    # that no link ever flips is ~1e-7 for any key stream
+    for i in range(12):
         state, _ = step(state, sampler(jax.random.PRNGKey(10 + i)),
                         jax.random.PRNGKey(i))
         masks.append(np.asarray(state.graph_state))

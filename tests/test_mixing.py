@@ -607,3 +607,71 @@ def test_process_validation():
                         DiffusionConfig(num_agents=4, drift_correction=True),
                         topology=make_topology("ring", 4),
                         participation=IIDBernoulli((0.5, 0.0, 0.5, 0.5)))
+
+
+# ---------------------------------------------------------------------------
+# mesh-aware backend choice: no single-device kernel across devices
+# ---------------------------------------------------------------------------
+
+def _two_device_mesh():
+    """A 2-device ("data",) mesh over the one real device, repeated: enough
+    for the policy, which reads only the mesh size."""
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices() * 2), ("data",))
+
+
+def test_auto_avoids_the_kernel_across_devices(monkeypatch):
+    from repro.core import graphs as graph_lib
+    from repro.core import mixing
+    monkeypatch.setattr(mixing.jax, "default_backend", lambda: "tpu")
+    ring = make_topology("ring", 8)
+    mesh = _two_device_mesh()
+    assert mixing.resolve_auto(ring)[0] == "pallas"
+    assert mixing.resolve_auto(ring, mesh=mesh)[0] == "sparse"
+    assert isinstance(make_mixer("auto", ring, mesh=mesh),
+                      SparseCirculantMixer)
+    # a graph that leaves the base support: dense, not the kernel
+    erdos = graph_lib.TimeVaryingErdos(8, p=0.3, topology=ring)
+    assert graph_lib.resolve_mix_for_graph("auto", erdos) == "pallas"
+    assert graph_lib.resolve_mix_for_graph("auto", erdos, mesh) == "dense"
+
+
+def test_gather_mixer_uses_the_kernel_only_on_one_device(monkeypatch):
+    from repro.core import mixing
+    from repro.kernels import diffusion_mix as dm
+    topo = make_topology("ring", 8, hops=2)
+    calls = []
+    real = dm.gather_mix
+    monkeypatch.setattr(dm, "gather_mix",
+                        lambda *a, **kw: calls.append(1) or real(
+                            *a, **{**kw, "interpret": True}))
+    monkeypatch.setattr(mixing.jax, "default_backend", lambda: "tpu")
+    W = _rand_tree(KEY, 8)
+    m, A = jnp.ones((8,)), jnp.asarray(topo.A, jnp.float32)
+    gather = make_mixer("gather", topo)
+    gather(W, m, A)
+    assert calls == [1]                           # one device: the kernel
+    # the policy reads only the mesh the mixer was sharded over; the fake
+    # mesh cannot place arrays, so set it and skip the layout pins
+    gather._mesh, gather._agent_axis = _two_device_mesh(), "data"
+    monkeypatch.setattr(mixing, "_constrain_agent_stack",
+                        lambda tree, mesh, axis: tree)
+    gather(W, m, A)
+    assert calls == [1]                           # across devices: einsum
+
+
+def test_pallas_mixer_bf16_buffer_is_bit_identical_to_f32():
+    """bfloat16 leaves flatten to a bfloat16 buffer: the kernel upcasts
+    exactly and rounds once, so the result equals the float32 buffer's."""
+    topo = make_topology("ring", 6)
+    A = jnp.asarray(topo.A, jnp.float32)
+    m = jnp.array([1, 0, 1, 1, 1, 0], jnp.float32)
+    W = jax.tree.map(lambda x: x.astype(jnp.bfloat16), _rand_tree(KEY, 6))
+    mixer = PallasFusedMixer(tile_m=128, interpret=True)
+    out = mixer(W, m, A)
+    ref = mixer(jax.tree.map(lambda x: x.astype(jnp.float32), W), m, A)
+    for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(ref)):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(a).view(np.uint16),
+            np.asarray(b.astype(jnp.bfloat16)).view(np.uint16))

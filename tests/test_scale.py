@@ -9,8 +9,6 @@ interpret mode, a K=1024 smoke on three bounded-degree topologies, the
 loud O(K^2) fallback warning, the support-driven attach/detach in
 check_mixer_support, the int8 quantized-wire split, and the agent-axis
 sharding rule."""
-import re
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -323,12 +321,16 @@ def test_int8_pipeline_mesh_bit_identical_and_s8_on_wire():
                     jax.tree.leaves(outs["mesh"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # the quantized buffer is pinned with sharding constraints, so the
-    # lowered module carries int8 (not f32) tensors through @Sharding —
-    # what becomes the s8 all-gather under a real multi-device GSPMD run
+    # program carries int8 (not f32) tensors through a sharding constraint
+    # — what becomes the s8 all-gather under a real multi-device run.  Read
+    # from the jaxpr, which does not depend on the partitioner's lowering
     pipe = make_pipeline("dense", topo, compress="int8", mesh=mesh)
-    text = jax.jit(lambda W_, m_, A_, k_: pipe(W_, m_, A_, None, k_)[0]
-                   ).lower(W, m, A, key).as_text()
-    assert re.search(r"@Sharding.*tensor<[0-9x]+xi8>", text)
+    jaxpr = jax.make_jaxpr(
+        lambda W_, m_, A_, k_: pipe(W_, m_, A_, None, k_)[0])(W, m, A, key)
+    pinned = [v.aval.dtype for e in jaxpr.eqns
+              if e.primitive.name == "sharding_constraint"
+              for v in e.invars]
+    assert jnp.int8 in pinned
 
 
 # ---------------------------------------------------------------------------

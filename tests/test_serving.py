@@ -175,6 +175,31 @@ def test_serveloop_matches_single_request(decode_loop):
         np.testing.assert_array_equal(np.asarray(c.tokens), ref)
 
 
+@pytest.mark.parametrize("decode_loop", ["fused", "py"])
+def test_serveloop_serves_a_bfloat16_model(decode_loop):
+    """Published widths keep bfloat16 weights, so the decode logits are
+    bfloat16 while the loop carries float32 ones: both loops must still
+    emit exactly the single-request tokens."""
+    cfg = dataclasses.replace(get_config("smollm_360m").smoke,
+                              dtype="bfloat16")
+    params = tf.init_params(KEY, cfg)
+    loop = ServeLoop(cfg, params, slots=2, max_len=32,
+                     decode_loop=decode_loop, chunk=4)
+    rng = np.random.default_rng(3)
+    reqs = [Request(uid=i, max_new_tokens=6,
+                    prompt=rng.integers(0, cfg.vocab_size,
+                                        size=(8 + 4 * i,)).astype(np.int32))
+            for i in range(3)]
+    for r in reqs:
+        loop.submit(r)
+    done = loop.run()
+    assert sorted(c.uid for c in done) == [0, 1, 2]
+    for c in done:
+        ref = _single_request_reference(cfg, params, reqs[c.uid].prompt, 6,
+                                        32)
+        np.testing.assert_array_equal(np.asarray(c.tokens), ref)
+
+
 def test_serveloop_swap_under_load_replay():
     """>= 8 double-buffered param swaps while decodes are in flight: every
     emitted token replays exactly under its recorded checkpoint

@@ -4,6 +4,8 @@ These run on 8 forced host devices (subprocess-free: we only check specs
 here; the 8-device execution test lives in test_integration via pytest-forked
 style env isolation is avoided by using the default 1-device mesh for math
 and a spec-only check for the production mesh)."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +25,7 @@ def _fake_mesh(shape, axes):
 
 
 MESH = _fake_mesh((4, 2), ("data", "model"))
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def test_param_specs_divisibility_guard():
@@ -92,3 +95,40 @@ def test_cache_pspecs_long_context_shards_sequence():
 def test_serve_batch_pspec():
     assert tuple(sh.serve_batch_pspec(MESH, 32, 2))[0] == "data"
     assert tuple(sh.serve_batch_pspec(MESH, 1, 2))[0] is None
+
+
+def test_agent_mesh_needs_devices_that_divide_K():
+    from repro.launch.mesh import make_agent_mesh
+    assert make_agent_mesh(4) is None                 # one device here
+    two = jax.devices() * 2
+    assert make_agent_mesh(4, two).shape == {"data": 2}
+    assert make_agent_mesh(3, two) is None
+
+
+def test_place_agents_shards_the_agent_dimension():
+    from repro.launch.mesh import place_agents
+    mesh = Mesh(np.array(jax.devices()), ("data",))
+    tree = {"w": jnp.ones((4, 3)), "step": jnp.zeros(()),
+            "batch": jnp.ones((2, 4, 5))}
+    placed = place_agents(tree, mesh, num_agents=4)
+    assert placed["w"].sharding.spec == P("data", None)
+    assert placed["step"].sharding.spec == P()
+    assert placed["batch"].sharding.spec == P()      # dim 0 is not K
+    batch = place_agents({"tokens": jnp.ones((2, 4, 5))}, mesh,
+                         num_agents=4, agent_dim=1)
+    assert batch["tokens"].sharding.spec == P(None, "data", None)
+
+
+def test_compile_cache_goes_where_the_environment_says(monkeypatch):
+    from repro.launch import cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cache.enable_compile_cache() == str(cache.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cache.CACHE_DIR)
+        assert cache.CACHE_DIR.parent == ROOT
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
