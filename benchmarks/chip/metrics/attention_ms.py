@@ -1,0 +1,9 @@
+"""Device time of the attention core per block, in ms: operations under
+the ``attention`` scope (scores, softmax and the weighted values; not the
+projections) in every pass, so it overlaps the forward, backward and
+recompute times, on the device with the most (``scopes.py``)."""
+from benchmarks.chip import scopes
+
+
+def read(ctx):
+    return scopes.per_block_ms(ctx, lambda s, d: s.attention[d])

@@ -834,45 +834,6 @@ def bench_byzantine():
          f"ok={bounded and degraded}")
 
 
-def bench_kernel_micro():
-    """Kernel wall-time micro-benches (jnp streaming paths; CPU numbers are
-    structural only — TPU perf comes from the roofline analysis)."""
-    from repro.models.layers import flash_attention_jnp
-    from repro.models.ssm import ssd_chunked
-    from repro.core import make_topology
-    from repro.core.mixing import make_mixer
-
-    key = jax.random.PRNGKey(0)
-    B, S, H, Kv, D = 1, 2048, 8, 2, 64
-    q = jax.random.normal(key, (B, S, H, D), jnp.float32)
-    k = jax.random.normal(key, (B, S, Kv, D), jnp.float32)
-    v = jax.random.normal(key, (B, S, Kv, D), jnp.float32)
-    f = jax.jit(lambda q, k, v: flash_attention_jnp(q, k, v))
-    _, us = _time_us(lambda: f(q, k, v), reps=5)
-    _row("kernel_flash_attn_2k", us, f"S={S};H={H}")
-
-    b, s, h, p, n = 1, 2048, 8, 64, 64
-    x = jax.random.normal(key, (b, s, h, p))
-    dt = jax.nn.softplus(jax.random.normal(key, (b, s, h)))
-    A = -jnp.exp(jax.random.normal(key, (h,)) * 0.3)
-    Bm = jax.random.normal(key, (b, s, n))
-    Cm = jax.random.normal(key, (b, s, n))
-    g = jax.jit(lambda *a: ssd_chunked(*a, chunk=128)[0])
-    _, us = _time_us(lambda: g(x, dt, A, Bm, Cm), reps=5)
-    _row("kernel_ssd_2k", us, f"s={s};h={h}")
-
-    K = 16
-    topo = make_topology("ring", K)
-    A = jnp.asarray(topo.A, jnp.float32)
-    W = {"w": jax.random.normal(key, (K, 1024, 512))}
-    m = jnp.ones((K,))
-    for name in ("dense", "sparse", "pallas"):
-        mixer = make_mixer(name, topo, tile_m=4096)
-        jf = jax.jit(lambda W_, m_, A_, mx=mixer: mx(W_, m_, A_))
-        _, us = _time_us(lambda: jf(W, m, A), reps=10)
-        _row(f"kernel_mix_{name}_8M", us, f"K={K}")
-
-
 def bench_scale_K():
     """Agent-axis scaling sweep (EXPERIMENTS.md §Scaling the agent axis).
 
@@ -1442,7 +1403,6 @@ ALL_BENCHES = (
     bench_compression,
     bench_graph_process,
     bench_byzantine,
-    bench_kernel_micro,
     bench_scale_K,
     bench_serve,
     bench_async,

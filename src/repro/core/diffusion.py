@@ -186,25 +186,28 @@ def local_update_scan(grad_fn, params: PyTree, opt_state: PyTree,
             rngs = jax.random.split(jax.random.fold_in(loss_key, t),
                                     num_agents)
             grads = grad_fn(p, batch_t, rngs)
-        if grad_transform is not None:
-            updates, s_new = grad_transform(grads, s, p)
-        else:
-            updates, s_new = grads, s
-        m = mus if step_mask is None else mus * mask_t.astype(mus.dtype)
-        p = jax.tree.map(
-            lambda w, g: w - _bshape(m, w).astype(w.dtype) * g.astype(w.dtype),
-            p, updates)
-        if step_mask is not None and grad_transform is not None:
-            # identity update for frozen agents extends to the optimizer
-            # state; leaves without the (K, ...) agent axis (global
-            # counters, e.g. the privacy mechanism index) advance as usual
-            def keep_frozen(n, o):
-                if n.ndim >= 1 and n.shape[0] == mask_t.shape[0]:
-                    return jnp.where(_bshape(mask_t, n).astype(bool), n, o)
-                return n
-            s = jax.tree.map(keep_frozen, s_new, s)
-        else:
-            s = s_new
+        with jax.named_scope("apply"):
+            if grad_transform is not None:
+                updates, s_new = grad_transform(grads, s, p)
+            else:
+                updates, s_new = grads, s
+            m = mus if step_mask is None else mus * mask_t.astype(mus.dtype)
+            p = jax.tree.map(
+                lambda w, g: (w - _bshape(m, w).astype(w.dtype)
+                              * g.astype(w.dtype)), p, updates)
+            if step_mask is not None and grad_transform is not None:
+                # identity update for frozen agents extends to the
+                # optimizer state; leaves without the (K, ...) agent axis
+                # (global counters, e.g. the privacy mechanism index)
+                # advance as usual
+                def keep_frozen(n, o):
+                    if n.ndim >= 1 and n.shape[0] == mask_t.shape[0]:
+                        return jnp.where(_bshape(mask_t, n).astype(bool),
+                                         n, o)
+                    return n
+                s = jax.tree.map(keep_frozen, s_new, s)
+            else:
+                s = s_new
         return (p, s), None
 
     if loss_key is None:
@@ -215,8 +218,12 @@ def local_update_scan(grad_fn, params: PyTree, opt_state: PyTree,
         xs = (block_batch, jnp.arange(local_steps))
     if step_mask is not None:
         xs = (xs, step_mask)
-    (params, opt_state), _ = jax.lax.scan(
-        local_step, (params, opt_state), xs, length=local_steps)
+    # named scopes label the layers in the compiled program's op metadata;
+    # forward, backward and recompute are marked there by JAX itself
+    # (``jvp(``, ``transpose(``, ``rematted_computation``)
+    with jax.named_scope("local_update"):
+        (params, opt_state), _ = jax.lax.scan(
+            local_step, (params, opt_state), xs, length=local_steps)
     return params, opt_state
 
 

@@ -442,6 +442,7 @@ class PallasFusedMixer(Mixer):
                               tile_m=lay.tile_m, interpret=interpret)
         return self._unflatten(mixed, leaves, treedef, lay)
 
+    @jax.named_scope("flatten")
     def _flatten(self, leaves, lay, dtype=jnp.float32) -> jax.Array:
         K = leaves[0].shape[0]
         flat = jnp.concatenate(
@@ -450,6 +451,7 @@ class PallasFusedMixer(Mixer):
             flat = jnp.pad(flat, ((0, 0), (0, lay.M_padded - lay.M)))
         return flat
 
+    @jax.named_scope("unflatten")
     def _unflatten(self, flat, leaves, treedef, lay):
         outs, off = [], 0
         for leaf, n in zip(leaves, lay.sizes):

@@ -266,19 +266,23 @@ def make_block_step(
         check_engine_state(process, pipeline, compressor, state,
                            "block_step.init_state", graph=graph_proc,
                            privacy=privacy)
-        key_act, key_loss = jax.random.split(key)
-        key_comm = jax.random.fold_in(key, 0xC0)
-        active, part_state = process.sample(state.part_state, key_act)
-        A_t, graph_state = graph_proc.sample(state.graph_state,
-                                             jax.random.fold_in(key, 0x9A))
-        mus = part.step_size_matrix(config.step_size, active, q,
-                                    config.drift_correction)
+        # the scope names label the step's layers in the compiled
+        # program's op metadata, where a profiler trace is attributed
+        with jax.named_scope("sample"):
+            key_act, key_loss = jax.random.split(key)
+            key_comm = jax.random.fold_in(key, 0xC0)
+            active, part_state = process.sample(state.part_state, key_act)
+            A_t, graph_state = graph_proc.sample(
+                state.graph_state, jax.random.fold_in(key, 0x9A))
+            mus = part.step_size_matrix(config.step_size, active, q,
+                                        config.drift_correction)
         params, opt_state = local_update_scan(
             grad_fn, state.params, state.opt_state, mus, block_batch,
             local_steps=config.local_steps, grad_transform=grad_transform,
             loss_key=key_loss, num_agents=K, step_mask=step_mask)
-        params, comm_state = pipeline(params, active, A_t,
-                                      state.comm_state, key_comm)
+        with jax.named_scope("combine"):
+            params, comm_state = pipeline(params, active, A_t,
+                                          state.comm_state, key_comm)
         metrics = {"active": active}
         privacy_state = state.privacy_state
         if privacy is not None:

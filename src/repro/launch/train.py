@@ -27,6 +27,13 @@ topk|randk|int8|gauss`` + ``--compress-ratio``/``--error-feedback``; with
 ``--mix pallas --compress int8`` the fused dequantize+mix kernel runs).
 ``--checkpoint`` saves the full EngineState with the spec embedded, so
 ``serve --checkpoint`` rebuilds the exact engine with zero flags.
+
+Each block's host work runs in profiler spans: ``train.data`` (the block's
+batch), ``train.step`` (dispatch of the jitted step), ``train.offload``,
+``train.log`` (the privacy accountant's host sync and the log line) and, at
+the end, ``train.checkpoint``.  ``--trace-dir DIR`` records a profiler trace
+of blocks 1 onward (block 0 compiles) into DIR; the spans share the device
+trace's clock, so the device's idle gaps can be put down to them.
 """
 from __future__ import annotations
 
@@ -45,6 +52,8 @@ from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_agent_mesh, place_agents
 from repro.models import transformer as tf
 
+_span = jax.profiler.TraceAnnotation
+
 
 def main():
     ap = argparse.ArgumentParser()
@@ -52,6 +61,8 @@ def main():
     ap.add_argument("--checkpoint", default=None,
                     help="save the final EngineState (+ embedded spec) here")
     ap.add_argument("--log-every", type=int, default=1)
+    ap.add_argument("--trace-dir", default=None,
+                    help="record a profiler trace of blocks 1 onward here")
     args = ap.parse_args()
 
     spec = spec_from_args(args)
@@ -182,57 +193,74 @@ def main():
         eps_spent = epsilon_from_rdp_np(host_rdp, privacy.delta,
                                         privacy.orders)
     blocks_done = 0
-    for i in range(run.blocks):
-        if budget:
-            projected = epsilon_from_rdp_np(host_rdp + inc_bar,
-                                            privacy.delta, privacy.orders)
-            if projected > budget:
-                print(f"privacy budget: epsilon={eps_spent:.3f} spent, "
-                      f"next block projects to {projected:.3f} > "
-                      f"{budget:g} — halting after {blocks_done} blocks")
+    tracing = False
+    try:
+        for i in range(run.blocks):
+            if args.trace_dir and i == 1:
+                jax.profiler.start_trace(args.trace_dir)
+                tracing = True
+            if budget:
+                projected = epsilon_from_rdp_np(host_rdp + inc_bar,
+                                                privacy.delta, privacy.orders)
+                if projected > budget:
+                    print(f"privacy budget: epsilon={eps_spent:.3f} spent, "
+                          f"next block projects to {projected:.3f} > "
+                          f"{budget:g} — halting after {blocks_done} blocks")
+                    break
+            with _span("train.data"):
+                key, kb, ks = jax.random.split(key, 3)
+                batch = sample_block(i, kb)
+                if mesh is not None:
+                    batch = place_agents(batch, mesh, num_agents=K,
+                                         agent_dim=1)
+            with _span("train.step"):
+                state, metrics = jit_step(fetch(state), batch, ks)
+            with _span("train.offload"):
+                state = offload(state)
+            blocks_done = i + 1
+            log_block = i % args.log_every == 0
+            with _span("train.log"):
+                if privacy is not None and (budget or log_block):
+                    # host sync only when the value is consumed: every
+                    # block for budgeted runs (the halt reads it), log
+                    # blocks otherwise
+                    host_rdp = np.asarray(state.privacy_state["rdp"],
+                                          np.float64)
+                    eps_spent = epsilon_from_rdp_np(host_rdp, privacy.delta,
+                                                    privacy.orders)
+                if log_block:
+                    active = metrics["active"]
+                    losses = eval_loss(state.params,
+                                       jax.tree.map(lambda x: x[0], batch))
+                    wall = (f"  sim_wall={float(metrics['t_wall']):.1f}s"
+                            if is_async else "")
+                    eps = (f"  epsilon={eps_spent:.3f}"
+                           if eps_spent is not None else "")
+                    print(f"block {i:4d}  active={int(active.sum())}/{K}  "
+                          f"mean_loss={float(losses.mean()):.4f}  "
+                          f"spread={float(losses.max() - losses.min()):.4f}"
+                          f"  t={time.time() - t0:.1f}s{wall}{eps}")
+            if budget and eps_spent >= budget:
+                print(f"privacy budget spent: epsilon={eps_spent:.3f} >= "
+                      f"{budget:g} after {blocks_done} blocks — halting")
                 break
-        key, kb, ks = jax.random.split(key, 3)
-        batch = sample_block(i, kb)
-        if mesh is not None:
-            batch = place_agents(batch, mesh, num_agents=K, agent_dim=1)
-        state, metrics = jit_step(fetch(state), batch, ks)
-        state = offload(state)
-        blocks_done = i + 1
-        log_block = i % args.log_every == 0
-        if privacy is not None and (budget or log_block):
-            # host sync only when the value is consumed: every block for
-            # budgeted runs (the halt reads it), log blocks otherwise
-            host_rdp = np.asarray(state.privacy_state["rdp"], np.float64)
-            eps_spent = epsilon_from_rdp_np(host_rdp, privacy.delta,
-                                            privacy.orders)
-        if log_block:
-            active = metrics["active"]
-            losses = eval_loss(state.params,
-                               jax.tree.map(lambda x: x[0], batch))
-            wall = (f"  sim_wall={float(metrics['t_wall']):.1f}s"
-                    if is_async else "")
-            eps = (f"  epsilon={eps_spent:.3f}"
-                   if eps_spent is not None else "")
-            print(f"block {i:4d}  active={int(active.sum())}/{K}  "
-                  f"mean_loss={float(losses.mean()):.4f}  "
-                  f"spread={float(losses.max() - losses.min()):.4f}  "
-                  f"t={time.time() - t0:.1f}s{wall}{eps}")
-        if budget and eps_spent >= budget:
-            print(f"privacy budget spent: epsilon={eps_spent:.3f} >= "
-                  f"{budget:g} after {blocks_done} blocks — halting")
-            break
 
-    if args.checkpoint:
-        metadata = {"arch": spec.model.arch}
-        if privacy is not None:
-            # the guarantee the saved iterate carries — serve --checkpoint
-            # reports it next to the model
-            metadata["epsilon_spent"] = privacy.epsilon_np(
-                state.privacy_state)
-            metadata["privacy_delta"] = spec.privacy.delta
-        save_experiment(args.checkpoint, state, spec=spec, step=blocks_done,
-                        metadata=metadata)
-        print("saved", args.checkpoint)
+        if args.checkpoint:
+            metadata = {"arch": spec.model.arch}
+            if privacy is not None:
+                # the guarantee the saved iterate carries — serve
+                # --checkpoint reports it next to the model
+                metadata["epsilon_spent"] = privacy.epsilon_np(
+                    state.privacy_state)
+                metadata["privacy_delta"] = spec.privacy.delta
+            with _span("train.checkpoint"):
+                save_experiment(args.checkpoint, state, spec=spec,
+                                step=blocks_done, metadata=metadata)
+            print("saved", args.checkpoint)
+    finally:
+        if tracing:
+            jax.profiler.stop_trace()
+            print("trace of blocks 1 onward in", args.trace_dir)
 
 
 if __name__ == "__main__":

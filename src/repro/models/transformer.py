@@ -193,12 +193,14 @@ def _apply_block(cfg: ModelConfig, kind: str, bp: dict, x: jax.Array,
     q, k, v = L.qkv_project(bp["attn"], h, _adims(cfg), positions=positions,
                             rotary_pct=cfg.rotary_pct, theta=cfg.rope_theta,
                             qk_norm=cfg.qk_norm, norm_eps=cfg.norm_eps)
-    if cfg.use_kernels:
-        from repro.kernels.ops import attention_op
-        o = attention_op(q, k, v, causal=True, window=window)
-    else:
-        o = L.flash_attention_jnp(q, k, v, causal=True, window=window,
-                                  q_chunk=q_chunk, kv_chunk=kv_chunk)
+    # scores, softmax and the weighted values; the projections stay outside
+    with jax.named_scope("attention"):
+        if cfg.use_kernels:
+            from repro.kernels.ops import attention_op
+            o = attention_op(q, k, v, causal=True, window=window)
+        else:
+            o = L.flash_attention_jnp(q, k, v, causal=True, window=window,
+                                      q_chunk=q_chunk, kv_chunk=kv_chunk)
     B, S = x.shape[:2]
     o_proj = o.reshape(B, S, -1) @ bp["attn"]["wo"]
     if cfg.tp_barrier:
