@@ -387,9 +387,14 @@ class PallasFusedMixer(Mixer):
     """Fused mask+mix Pallas kernel over the flattened parameter pytree.
 
     The agent-stacked pytree is flattened to one (K, M) buffer padded to a
-    tile multiple — in the leaves' own dtype when they are all bfloat16,
-    float32 otherwise; the kernel rebuilds the eq.-20 masked matrix in VMEM
-    per tile, accumulates in float32, and streams the buffer exactly once.
+    multiple of ``tile_m`` — in the leaves' own dtype when they are all
+    bfloat16, float32 otherwise; the kernel sizes its grid's blocks from K,
+    M and that dtype (MiBs of the buffer per step, within a VMEM budget),
+    rebuilds the eq.-20 masked matrix in VMEM per block, accumulates in
+    float32, and streams the buffer exactly once.  ``tile_m`` is the padding
+    quantum and the width the kernel's contraction chunks are multiples of,
+    not the block; it is also the int8 path's scale granularity (one scale
+    per agent and tile).
     A bfloat16 buffer gives bit-identical results to a float32 one (the
     upcast is exact and the output is rounded to bfloat16 once either
     way) at half the HBM footprint and traffic; in a full-width block step

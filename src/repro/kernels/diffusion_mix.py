@@ -2,18 +2,21 @@
 
 Fuses the per-sample-path masking of the combination matrix (eq. 20) with
 the parameter mix  W'_k = sum_l a_lk W_l , so the masked (K, K) matrix is
-(re)built in VMEM registers per tile and never round-trips to HBM, and the
-stacked parameter matrix is streamed exactly once.
+(re)built in VMEM registers per grid step and never round-trips to HBM, and
+the stacked parameter matrix is streamed exactly once.
 
 Layout: the agent-stacked parameter tree is flattened to (K, M); the grid
-tiles M.  K is small (<= 64 agents), so the (K, K) mix lives comfortably in
-VMEM next to a (K, tile_m) parameter tile; tile_m is a multiple of 128 for
-lane alignment.
+walks M.  tile_m (a multiple of 128, for lane alignment) is the quantum the
+caller pads M to.  :func:`diffusion_mix` sizes its (K, block) blocks from K,
+M and the dtype to fill a VMEM budget, so each grid step moves MiBs of the
+stack rather than one tile, and contracts them a chunk of tiles at a time;
+K is small (<= 64 agents), so the (K, K) mix lives comfortably in VMEM
+beside them.  The other kernels move one (K, tile_m) tile per grid step.
 
 Four variants:
 
-* :func:`diffusion_mix` — float32 buffer (the PR-1 kernel).  Materializes
-  the (K, K) matrix per tile: the right shape when K is small (<= a few
+* :func:`diffusion_mix` — float32 or bfloat16 buffer.  Materializes the
+  (K, K) matrix per block: the right shape when K is small (<= a few
   hundred agents).
 * :func:`diffusion_mix_int8` — the compressed-communication path: the
   buffer arrives *quantized* (int8 values + one float32 scale per (agent,
@@ -49,12 +52,24 @@ the smallest block the TPU lowering accepts.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+
+# the combination kernel's blocks.  A grid step costs ~0.5 us on a v5e
+# whatever it moves, so a block carries MiBs of the stack.  Inside a block
+# each contraction takes a float32 operand of ~128 KiB, and one loop
+# iteration runs 16 of them, which the scheduler overlaps (at K=4 on a v5e,
+# 16 x 4096 columns per iteration beat one 65536-column contraction by 9%).
+_MIX_VMEM_BUDGET = 48 * 2**20     # of a v5e's 128 MiB of VMEM
+_VMEM_DEFAULT_LIMIT = 16 * 2**20  # a v5e's default scoped VMEM limit
+_MIX_CHUNK_BYTES = 128 * 2**10    # float32 operand of one contraction
+_MIX_UNROLL = 16                  # contractions per loop iteration
+_MIX_F32_TEMPS = 4                # float32 temporaries per contraction
 
 # per-tile int8 scales travel in lane-dense (K, 128) blocks: a (K, 1) block
 # per tile is refused by the TPU lowering (last block dim must be a multiple
@@ -86,14 +101,23 @@ def _contract(A_eff: jax.Array, W: jax.Array) -> jax.Array:
                                preferred_element_type=jnp.float32)
 
 
-def _mix_kernel(a_ref, m_ref, w_ref, o_ref, *, K: int):
+def _mix_kernel(a_ref, m_ref, w_ref, o_ref, *, K: int, chunk: int,
+                unroll: int):
     A = a_ref[...].astype(jnp.float32)                  # (K, K)
     m = m_ref[...].astype(jnp.float32)[:, 0]            # (K,)
-    W = w_ref[...].astype(jnp.float32)                  # (K, TM)
     A_eff = _masked_matrix(A, m, K)
 
-    # W'_k = sum_l A_eff[l, k] W[l]  ==  A_eff^T @ W
-    o_ref[...] = _contract(A_eff, W).astype(o_ref.dtype)
+    # W'_k = sum_l A_eff[l, k] W[l]  ==  A_eff^T @ W, over the block's
+    # columns one chunk at a time, ``unroll`` chunks per loop iteration
+    def body(j, carry):
+        for u in range(unroll):
+            start = pl.multiple_of((j * unroll + u) * chunk, chunk)
+            cols = pl.ds(start, chunk)
+            W = w_ref[:, cols].astype(jnp.float32)      # (K, chunk)
+            o_ref[:, cols] = _contract(A_eff, W).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, w_ref.shape[1] // (chunk * unroll), body, 0)
 
 
 def _mix_int8_kernel(a_ref, m_ref, wq_ref, s_ref, o_ref, *, K: int,
@@ -111,35 +135,97 @@ def _mix_int8_kernel(a_ref, m_ref, wq_ref, s_ref, o_ref, *, K: int,
     o_ref[...] = _contract(A_eff, W).astype(o_ref.dtype)
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _sublanes(dtype) -> int:
+    """Rows of the dtype's VMEM tile: 8 for float32, 16 for bfloat16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def _mix_vmem_bytes(K: int, block: int, chunk: int, unroll: int,
+                    dtype) -> int:
+    """VMEM the combination kernel holds for (K, block) blocks: the input
+    and output blocks double-buffered with K padded to the dtype's sublane
+    tile, A and the mask double-buffered, and the float32 temporaries of one
+    loop iteration's ``unroll`` contractions of ``chunk`` columns (the
+    upcast, the operands split into bfloat16 passes, the result)."""
+    rows, rows32 = _round_up(K, _sublanes(dtype)), _round_up(K, 8)
+    blocks = 2 * 2 * rows * block * jnp.dtype(dtype).itemsize
+    small = 2 * 2 * rows32 * _round_up(K, 128) * 4
+    temps = _MIX_F32_TEMPS * rows32 * chunk * unroll * 4
+    return blocks + small + temps
+
+
+def _mix_blocks(K: int, M: int, dtype, tile_m: int) -> tuple[int, int, int]:
+    """(block, chunk, unroll): the columns one grid step moves, the columns
+    of one contraction, and the contractions per loop iteration.  The chunk
+    is the multiple of ``tile_m`` (itself a multiple of 128) nearest below
+    ``_MIX_CHUNK_BYTES`` of float32 rows; the block is the widest multiple of
+    ``unroll`` chunks whose :func:`_mix_vmem_bytes` fits ``_MIX_VMEM_BUDGET``
+    (so it narrows as K grows), and the whole stack where that is wider, in
+    chunks and iterations that divide it."""
+    rows32 = _round_up(K, 8)
+    chunk = max(tile_m, _MIX_CHUNK_BYTES // (4 * rows32) // tile_m * tile_m)
+    step = chunk * _MIX_UNROLL
+    per_column = (_mix_vmem_bytes(K, 1, 0, 0, dtype)
+                  - _mix_vmem_bytes(K, 0, 0, 0, dtype))
+    room = _MIX_VMEM_BUDGET - _mix_vmem_bytes(K, 0, chunk, _MIX_UNROLL, dtype)
+    block = max(step, room // per_column // step * step)
+    if block < M:
+        return block, chunk, _MIX_UNROLL
+    chunk = tile_m * math.gcd(M // tile_m, chunk // tile_m)
+    return M, chunk, math.gcd(M // chunk, _MIX_UNROLL)
+
+
+def _mix_call(A, active, W, *, block: int, chunk: int, unroll: int,
+              interpret: bool):
+    K, M = W.shape
+    need = _mix_vmem_bytes(K, block, chunk, unroll, W.dtype)
+    return pl.pallas_call(
+        functools.partial(_mix_kernel, K=K, chunk=chunk, unroll=unroll),
+        grid=(pl.cdiv(M, block),),
+        in_specs=[
+            pl.BlockSpec((K, K), lambda mi: (0, 0)),
+            pl.BlockSpec((K, 1), lambda mi: (0, 0)),
+            pl.BlockSpec((K, block), lambda mi: (0, mi)),
+        ],
+        out_specs=pl.BlockSpec((K, block), lambda mi: (0, mi)),
+        out_shape=jax.ShapeDtypeStruct((K, M), W.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(need, _VMEM_DEFAULT_LIMIT)),
+        interpret=interpret,
+    )(A, active.reshape(K, 1), W)
+
+
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
 def diffusion_mix(A: jax.Array, active: jax.Array, W: jax.Array, *,
                   tile_m: int = 512, interpret: bool = False) -> jax.Array:
     """Masked combination step over flattened stacked parameters.
 
+    Each grid step moves one (K, block) block of the stack, the block sized
+    from K, M and W's dtype by :func:`_mix_blocks`; the last block may be
+    ragged.  Inside a block the contraction runs over chunks of a multiple
+    of ``tile_m`` columns.  Every output column is A_eff^T @ W[:, j] in
+    float32, rounded to W's dtype once, whatever the block.
+
     Args:
       A: (K, K) base combination matrix.
       active: (K,) activation mask in {0, 1}.
-      W: (K, M) stacked flattened parameters; M % tile_m == 0 (pad upstream).
+      W: (K, M) stacked flattened parameters; M % tile_m == 0 (pad upstream),
+        tile_m % 128 == 0.
     Returns:
       (K, M) mixed parameters, dtype of W.
     """
     K, M = W.shape
+    if tile_m % 128:
+        raise ValueError(f"tile_m={tile_m} must be a multiple of 128")
     if M % tile_m:
         raise ValueError(f"M={M} not divisible by tile_m={tile_m}")
-    nm = M // tile_m
-    kernel = functools.partial(_mix_kernel, K=K)
-    return pl.pallas_call(
-        kernel,
-        grid=(nm,),
-        in_specs=[
-            pl.BlockSpec((K, K), lambda mi: (0, 0)),
-            pl.BlockSpec((K, 1), lambda mi: (0, 0)),
-            pl.BlockSpec((K, tile_m), lambda mi: (0, mi)),
-        ],
-        out_specs=pl.BlockSpec((K, tile_m), lambda mi: (0, mi)),
-        out_shape=jax.ShapeDtypeStruct((K, M), W.dtype),
-        interpret=interpret,
-    )(A, active.reshape(K, 1), W)
+    block, chunk, unroll = _mix_blocks(K, M, W.dtype, tile_m)
+    return _mix_call(A, active, W, block=block, chunk=chunk, unroll=unroll,
+                     interpret=interpret)
 
 
 @functools.partial(jax.jit,

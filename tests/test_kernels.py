@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode, shape/dtype sweeps)."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,6 +9,7 @@ import pytest
 from repro.core import make_topology
 from repro.core.participation import masked_combination
 from repro.core.sharded import mix_dense
+from repro.kernels import diffusion_mix as dm
 from repro.kernels.ops import attention_op, mix_op, ssd_op
 from repro.kernels.ref import attention_ref, mix_ref, ssd_ref
 from repro.models.ssm import ssd_chunked
@@ -134,6 +137,96 @@ def test_mix_kernel_full_participation_identity():
     A = jnp.eye(K)
     active = jnp.ones((K,))
     W = jax.random.normal(KEY, (K, 256))
-    from repro.kernels.diffusion_mix import diffusion_mix
-    out = diffusion_mix(A, active, W, tile_m=128, interpret=True)
+    out = dm.diffusion_mix(A, active, W, tile_m=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(W), atol=1e-6)
+
+
+TILE = 128
+ROOT = Path(__file__).resolve().parents[1]
+
+
+def _cell_stack(config: str, K: int) -> tuple[int, int]:
+    """(K, M) of a benchmark cell's stack: one agent's parameters padded to
+    the mixer's 512-column tile."""
+    from benchmarks.chip import model
+    cfg = model.model_config(model.load_json(
+        ROOT / "benchmarks" / "chip" / "configs" / f"{config}.json"))
+    n = cfg.total_params()
+    return K, n + (-n) % 512
+
+
+def _mix_case(K, M, dtype):
+    W = jax.random.normal(jax.random.fold_in(KEY, K), (K, M), dtype)
+    A = jnp.asarray(make_topology("ring", K).A, jnp.float32)
+    active = jnp.ones((K,)).at[K - 1].set(0.0)      # one agent sits out
+    return A, active, W
+
+
+@pytest.mark.parametrize("K,dtype,budget", [
+    (K, dtype, "small") for K in (2, 4) for dtype in (jnp.float32, jnp.bfloat16)
+] + [(2, jnp.float32, "default"), (4, jnp.float32, "default")])
+def test_mix_kernel_block_invariance(monkeypatch, K, dtype, budget):
+    """Wide blocks with a ragged last one (M = 3 blocks + 640 columns) give
+    bit for bit what one 128-column tile per grid step gives, and match the
+    dense combination.  ``small`` shrinks the contraction to two tiles and
+    the VMEM budget to blocks of two loop iterations, so that the
+    tile-per-step reference stays quick in bfloat16 (~40 s here at the
+    default budget's blocks)."""
+    if budget == "small":
+        monkeypatch.setattr(dm, "_MIX_CHUNK_BYTES", 2 * TILE * 8 * 4)
+        _, chunk, unroll = dm._mix_blocks(K, 1 << 40, dtype, TILE)
+        monkeypatch.setattr(dm, "_MIX_VMEM_BUDGET", dm._mix_vmem_bytes(
+            K, 2 * chunk * unroll, chunk, unroll, dtype))
+    block, chunk, unroll = dm._mix_blocks(K, 1 << 40, dtype, TILE)
+    M = 3 * block + 640
+    A, active, W = _mix_case(K, M, dtype)
+    # jit afresh, so the (possibly patched) sizes are read at trace time
+    mix = jax.jit(dm.diffusion_mix.__wrapped__,
+                  static_argnames=("tile_m", "interpret"))
+    out = mix(A, active, W, tile_m=TILE, interpret=True)
+    assert dm._mix_blocks(K, M, dtype, TILE) == (block, chunk, unroll)
+    assert unroll > 1 and block > chunk * unroll
+    tiles = dm._mix_call(A, active, W, block=TILE, chunk=TILE, unroll=1,
+                         interpret=True)
+    assert out.dtype == W.dtype
+    np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                  np.asarray(tiles.astype(jnp.float32)))
+    ref = mix_dense(masked_combination(A, active), W.astype(jnp.float32))
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5, rtol=1e-5)
+    else:      # one rounding of the float32 result to bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(out.astype(jnp.float32)),
+            np.asarray(ref.astype(jnp.bfloat16).astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("config,agents", [("smollm-360m", 4),
+                                           ("chatglm3-6b-d4v4", 2)])
+def test_mix_blocks_at_the_cells_shapes(config, agents):
+    """At the cells' bfloat16 stacks each grid step moves >= 1 MiB of the
+    stack (read + write), within the VMEM it was sized by; the block is
+    lane-aligned and holds whole chunks of whole tiles."""
+    K, M = _cell_stack(config, agents)
+    block, chunk, unroll = dm._mix_blocks(K, M, jnp.bfloat16, 512)
+    assert 2 * K * block * 2 >= 2**20
+    assert dm._mix_vmem_bytes(K, block, chunk, unroll, jnp.bfloat16) \
+        <= dm._MIX_VMEM_BUDGET
+    assert block % 128 == 0 and chunk % 512 == 0
+    assert block % (chunk * unroll) == 0
+    assert -(-M // block) < M // 512 // 100     # 100x fewer grid steps
+
+
+def test_mix_blocks_shrink_with_K_and_clamp_to_narrow_stacks():
+    wide = dm._mix_blocks(4, 1 << 30, jnp.float32, 512)[0]
+    block, chunk, unroll = dm._mix_blocks(64, 1 << 30, jnp.float32, 512)
+    assert 512 <= block < wide
+    assert dm._mix_vmem_bytes(64, block, chunk, unroll, jnp.float32) \
+        <= dm._MIX_VMEM_BUDGET
+    # narrower than one block: the whole stack in a single step, in chunks
+    # and loop iterations that divide it
+    assert dm._mix_blocks(4, 640, jnp.float32, 128) == (640, 128, 1)
+    assert dm._mix_blocks(4, 3 * 2048, jnp.bfloat16, 512) == (3 * 2048,
+                                                              2048, 1)
+    assert dm._mix_blocks(4, 8 * 4096, jnp.bfloat16, 512) == (8 * 4096,
+                                                              4096, 8)

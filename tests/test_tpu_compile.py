@@ -4,7 +4,8 @@ Interpret mode on the CPU checks what the kernels compute; only the TPU
 compiler checks that they lower at all (block shapes, memory spaces, VMEM).
 Each test compiles one kernel ahead of time for one chip of a described
 ``v5e:2x2`` topology at smollm-360m's width — K=4 agents, M its parameter
-count padded to the tile — and finds the native kernel in the result.
+count padded to the tile — (the combination kernel also at chatglm3-6b-d4v4's,
+K=2) and finds the native kernel in the result.
 Nothing runs, so no chip is needed.
 
 The topology is described inside a module fixture, never at import: only
@@ -12,6 +13,7 @@ one process at a time may load the TPU library, and under pytest-xdist only
 the worker that is given this file should.
 """
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +57,14 @@ def M():
     return _padded(get_config("smollm-360m").model.total_params())
 
 
+def _chatglm_M() -> int:
+    from benchmarks.chip import model
+    cfg = model.model_config(model.load_json(
+        Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+        / "configs" / "chatglm3-6b-d4v4.json"))
+    return _padded(cfg.total_params())
+
+
 def _compile_native(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -65,12 +75,19 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_diffusion_mix_compiles(one_chip, M, dtype):
+# smollm-360m's stack at K=4 in both dtypes, and chatglm3-6b-d4v4's at K=2
+# in bfloat16: the two cells' block widths lower and fit the VMEM they ask for
+@pytest.mark.parametrize("k,dtype,chatglm", [
+    pytest.param(K, jnp.float32, False, id="float32"),
+    pytest.param(K, jnp.bfloat16, False, id="bfloat16"),
+    pytest.param(2, jnp.bfloat16, True, id="chatglm-bfloat16"),
+])
+def test_diffusion_mix_compiles(one_chip, M, k, dtype, chatglm):
+    width = _chatglm_M() if chatglm else M
     _compile_native(
         lambda A, a, W: dm.diffusion_mix(A, a, W, tile_m=TILE),
-        _sds((K, K), jnp.float32, one_chip), _sds((K,), jnp.float32, one_chip),
-        _sds((K, M), dtype, one_chip))
+        _sds((k, k), jnp.float32, one_chip), _sds((k,), jnp.float32, one_chip),
+        _sds((k, width), dtype, one_chip))
 
 
 @pytest.mark.parametrize("subtract_identity", [False, True])
