@@ -3,18 +3,24 @@
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
 its configuration file, its traffic file (``traffic/<traffic>.json``), its
 limits (``limits/<cell>.json``), its configuration's plain reference
-(``reference/<reference>.py``) and each per-layer metric's reader
-(``metrics/<metric>.py``).  A new cell is new files and entries only.
+(``reference/<reference>.py``) and family module
+(``layouts/<reference>.py``: the weights' layout and the model FLOP of a
+token), and each per-layer metric's reader (``metrics/<metric>.py``).  A
+new cell, and a configuration of a new family, is new files and entries
+only.
 
 A run:
 
 1. builds the program's engine from the cell (``repro.api.build``), makes
-   the weights and the token stream on the device from the seed, and
-   jits the engine's block step with the state donated;
+   the weights (by the family module) and the token stream on the device
+   from the seed, and jits the engine's block step with the state donated;
 2. drives that compiled step through the first ``check_blocks`` blocks,
    keeping what :mod:`check` compares;
-3. measures: blocks back to back for ``seconds``, each waited for before
-   the next is dispatched, with the profiler on when ``trace``;
+3. measures: blocks back to back for ``seconds``, with about ``AHEAD_S``
+   seconds of them dispatched ahead of the one waited for, then waits for
+   every block sent; the profiler is on when ``trace``, and a traced run
+   then hands the readers the trace and the compiled block step's scope
+   map (``scopes.parse_hlo``) in ``ctx``;
 4. reads the peak device memory, frees the program's state, and runs the
    plain reference over the checked blocks.
 
@@ -26,8 +32,11 @@ step's temporaries.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import gc
 import importlib.util
+import math
 import shutil
 import sys
 import tempfile
@@ -37,10 +46,11 @@ from pathlib import Path
 
 import numpy as np
 
-from benchmarks.chip import check, counts, model, trace
+from benchmarks.chip import check, counts, model, scopes, trace
 from benchmarks.chip.peaks import peaks_for
 
-__all__ = ["Cell", "load_cell", "run", "NoChip", "ROOT", "BENCH"]
+__all__ = ["Cell", "load_cell", "load_layout", "reader_context", "run",
+           "NoChip", "ROOT", "BENCH"]
 
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = Path(__file__).resolve().parent
@@ -48,6 +58,9 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 #: faults a test or the calibration plants in the timed path; runs of the
 #: benchmark itself never do
 FAULTS = ("none", "half_batch", "frozen_state", "no_exchange")
+#: seconds of device work the window keeps dispatched ahead of the block it
+#: waits for
+AHEAD_S = 4.0
 
 
 class NoChip(RuntimeError):
@@ -107,6 +120,13 @@ def _load_module(path: Path, name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_layout(cell: Cell):
+    """The family module of the cell's configuration,
+    ``layouts/<reference>.py``."""
+    name = cell.config["reference"]
+    return _load_module(_code(cell, "layouts", name), "bench_layout_" + name)
 
 
 def run(workload: str, seed: int, seconds: float, trace_on: bool, *,
@@ -176,7 +196,8 @@ def _run(cell, devs, log, peaks, counter, seed, seconds, trace_on, fault,
     tr = cell.traffic
     K, T = tr["agents"], tr["local_steps"]
     cfg = model.model_config(cell.config)
-    model.check_program_layout(cfg)
+    layout = load_layout(cell)
+    model.check_program_layout(cfg, layout)
     mkind = model.register_model(cfg, half_batch=fault == "half_batch")
     eng, mesh = _build(cell, cfg, mkind, seed, devs)
     if fault == "frozen_state":
@@ -193,18 +214,19 @@ def _run(cell, devs, log, peaks, counter, seed, seconds, trace_on, fault,
     key = model.seed_key(seed)
     k_w, k_data, k_step = jax.random.split(key, 3)
 
-    init = jax.jit(lambda k: model.to_program(
-        check.broadcast_agents(model.make_weights(k, cfg), K),
+    init = jax.jit(lambda k: layout.to_program(
+        check.broadcast_agents(layout.make_weights(k, cfg), K),
         cfg), out_shardings=stack)
     gen = jax.jit(lambda k, i: model.make_block(k, i, tr, cfg.vocab_size),
                   out_shardings=per_step)
-    fp = jax.jit(lambda p: check.fingerprint(model.from_program(p)))
+    fp = jax.jit(lambda p: check.fingerprint(layout.from_program(p, cfg)))
     # the initial weights are drawn anew for each comparison, so no copy of
     # them is held while the step runs; they are drawn by a call of their
     # own, whose output is rounded to the weights' dtype: inside one fused
     # program the compiler may keep their unrounded value
-    one_agent = jax.jit(lambda k: model.make_weights(k, cfg))
-    chg = jax.jit(lambda p, w0: check.sq_change(model.from_program(p), w0))
+    one_agent = jax.jit(lambda k: layout.make_weights(k, cfg))
+    chg = jax.jit(lambda p, w0: check.sq_change(layout.from_program(p, cfg),
+                                                w0))
 
     params = init(k_w)
     state = eng.init_state(params, eng.optimizer.init(params),
@@ -219,8 +241,12 @@ def _run(cell, devs, log, peaks, counter, seed, seconds, trace_on, fault,
     actives, changes = [], []
     n_check = tr["check_blocks"]
     for b in range(n_check):
+        t_b = time.perf_counter()
         state, met = step(state, gen(k_data, b), jax.random.fold_in(k_step, b))
         actives.append(np.asarray(met["active"]))
+        # one block's wall time, data and step, waited for: it sizes how
+        # many blocks the window keeps in flight
+        block_est = time.perf_counter() - t_b
         prints.append(np.asarray(fp(state.params)))
         if b in (0, n_check - 1):
             changes.append(np.asarray(chg(state.params, one_agent(k_w))))
@@ -232,38 +258,76 @@ def _run(cell, devs, log, peaks, counter, seed, seconds, trace_on, fault,
     jax.block_until_ready(state)
 
     # -- the measured window -------------------------------------------------
-    n_blocks, drawn = 0, []
+    # blocks are dispatched ahead of the one waited for, about AHEAD_S of
+    # device work, so the chip stays fed while the host stands still; when
+    # the time is up nothing more is sent, every block sent is waited for,
+    # and the clock is read after that wait: all of them count, over all of
+    # that time
+    depth = max(1, math.ceil(AHEAD_S / block_est))
+    n_blocks, drawn, done_at, pending = 0, [], [], collections.deque()
     prof_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace_on else None
+    # the interpreter's garbage collections inside the window, each as
+    # (generation, seconds): a host stall the log can then name
+    gc_pauses, gc_start = [], []
+
+    def on_gc(phase, info):
+        if phase == "start":
+            gc_start.append(time.perf_counter())
+        elif gc_start:
+            gc_pauses.append((info["generation"],
+                              time.perf_counter() - gc_start.pop()))
+
     counter["on"] = True
+    gc.callbacks.append(on_gc)
     t0 = time.perf_counter()
     setup_s = t0 - t_start
-    if trace_on:
-        jax.profiler.start_trace(prof_dir)
-    b = n_check
-    while True:
-        with jax.profiler.TraceAnnotation("bench.block"):
-            with jax.profiler.TraceAnnotation("bench.data"):
-                batch = gen(k_data, b)
-            with jax.profiler.TraceAnnotation("bench.dispatch"):
-                state, met = step(state, batch,
-                                  jax.random.fold_in(k_step, b))
-            with jax.profiler.TraceAnnotation("bench.wait"):
-                jax.block_until_ready(state)
-        drawn.append(met["active"])
-        n_blocks += 1
-        b += 1
-        if time.perf_counter() - t0 >= seconds:
-            break
-    elapsed = time.perf_counter() - t0
-    if trace_on:
-        jax.profiler.stop_trace()
-    counter["on"] = False
+    try:
+        if trace_on:
+            jax.profiler.start_trace(prof_dir)
+        b = n_check
+        up = False
+        while not up:
+            # the last block's span holds the final wait, so the spans run
+            # from the first dispatch to the end of the device's work
+            with jax.profiler.TraceAnnotation("bench.block"):
+                with jax.profiler.TraceAnnotation("bench.data"):
+                    batch = gen(k_data, b)
+                with jax.profiler.TraceAnnotation("bench.dispatch"):
+                    state, met = step(state, batch,
+                                      jax.random.fold_in(k_step, b))
+                drawn.append(met["active"])
+                pending.append(met)
+                n_blocks += 1
+                b += 1
+                with jax.profiler.TraceAnnotation("bench.wait"):
+                    if len(pending) > depth:
+                        jax.block_until_ready(pending.popleft())
+                        done_at.append(time.perf_counter())
+                    up = time.perf_counter() - t0 >= seconds
+                    if up:
+                        jax.block_until_ready((state, list(pending)))
+        elapsed = time.perf_counter() - t0
+        if trace_on:
+            jax.profiler.stop_trace()
+    finally:
+        gc.callbacks.remove(on_gc)
+        counter["on"] = False
     realized = (float(np.sum(jax.device_get(drawn))) * T * tr["batch"]
                 * tr["seq"])
     tokens = n_blocks * counts.block_tokens(tr)
     log(f"window: {n_blocks} blocks in {elapsed:.4f} s "
         f"({elapsed / n_blocks:.4f} s per block); tokens at configured "
         f"q={tr['participation']}: {tokens:.0f}, realized: {realized:.0f}")
+    # the times between the ends of blocks waited for, once the queue is
+    # full: one block's device time each, unless a host stall outlasts the
+    # queue
+    walls = np.diff(done_at) if len(done_at) > 1 else np.array([elapsed])
+    log(f"{depth} blocks dispatched ahead ({block_est:.4f} s per checked "
+        f"block); block wall times once full: median "
+        f"{np.median(walls):.4f} s, slowest {walls.max():.4f} s; "
+        f"garbage collections in the window: {len(gc_pauses)}, of "
+        f"generation 2: {sum(g == 2 for g, _ in gc_pauses)}, longest "
+        f"{max((d for _, d in gc_pauses), default=0.0):.4f} s")
     log(f"compilations inside the window: {counter['window']}; compile "
         f"cache over the run: {counter['hits']} hits, "
         f"{counter['misses']} misses")
@@ -279,17 +343,23 @@ def _run(cell, devs, log, peaks, counter, seed, seconds, trace_on, fault,
 
     metrics = {}
     if trace_on:
+        t1 = time.perf_counter()
+        step_hlo = scopes.parse_hlo(step.as_text())
+        log(f"scope map of the block step: {len(step_hlo.op_names)} "
+            f"instructions in {time.perf_counter() - t1:.3f} s")
         events = trace.events_from_xplane(prof_dir)
         shutil.rmtree(prof_dir, ignore_errors=True)
-        metrics, dev_info, breakdown = _per_layer(
-            cell, events, cfg, peaks, agent_params, itemsize, log)
+        ctx = reader_context(cell, events, cfg, layout, step_hlo, peaks,
+                             agent_params, itemsize)
+        metrics, dev_info, breakdown = _per_layer(cell, ctx, log)
     else:
         e2e = {"train_tokens_per_s": tokens / elapsed / cell.chips,
                "setup_s": setup_s}
         for m in cell.end_to_end:
             metrics[m["name"]] = {"value": e2e[m["name"]], "unit": m["unit"]}
 
-    checks, ctl, arrays = _check(cell, cfg, k_w, lambda b: gen(k_data, b),
+    checks, ctl, arrays = _check(cell, cfg, layout, k_w,
+                                 lambda b: gen(k_data, b),
                                  actives, prints, changes, control=control)
     correct = _passes(checks)
     for name, c in checks.items():
@@ -349,8 +419,14 @@ def _build(cell: Cell, cfg, mkind: str, seed: int, devs):
     return build(spec, mesh=mesh), mesh
 
 
-def _per_layer(cell, events, cfg, peaks, agent_params, itemsize, log):
-    """Per-layer metrics, device busy time and the breakdown of a trace."""
+def reader_context(cell, events, cfg, layout, step_hlo, peaks,
+                   agent_params, itemsize) -> types.SimpleNamespace:
+    """What the per-layer readers get: the trace's events, devices and
+    window of blocks; the cell's configuration, traffic, chips and family
+    module (``layout``); the compiled block step's scope map
+    (``step_hlo``); the chip's peaks; one agent's parameter count and their
+    bytes each; and ``note``, which keeps a line for the run's log
+    (``notes``)."""
     devices = trace.devices(events)
     if not devices:
         raise RuntimeError("the trace holds no device operation")
@@ -358,11 +434,17 @@ def _per_layer(cell, events, cfg, peaks, agent_params, itemsize, log):
     n = sum(1 for e in events if e[0] == trace.HOST
             and e[1] == trace.SPAN_PREFIX + "block")
     notes = []
-    ctx = types.SimpleNamespace(
+    return types.SimpleNamespace(
         events=events, devices=devices, lo=lo, hi=hi, n_blocks=n,
         block_s=(hi - lo) * 1e-9 / n, chips=cell.chips, peaks=peaks,
-        cfg=cfg, traffic=cell.traffic, agent_params=agent_params,
-        itemsize=itemsize, note=notes.append)
+        cfg=cfg, layout=layout, step_hlo=step_hlo, traffic=cell.traffic,
+        agent_params=agent_params, itemsize=itemsize, notes=notes,
+        note=notes.append)
+
+
+def _per_layer(cell, ctx, log):
+    """Per-layer metrics, device busy time and the breakdown of a trace."""
+    events, devices, lo, hi = ctx.events, ctx.devices, ctx.lo, ctx.hi
     metrics = {}
     for m in cell.per_layer:
         reader = _load_module(_code(cell, "metrics", m["name"]),
@@ -370,20 +452,20 @@ def _per_layer(cell, events, cfg, peaks, agent_params, itemsize, log):
         v = reader.read(ctx)
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
-    for line in notes:
+    for line in ctx.notes:
         log(line)
     busy = [trace.busy_ns(events, d, lo, hi) * 1e-9 for d in devices]
     busiest = devices[int(np.argmax(busy))]
     breakdown = {"device_ops": trace.top_ops(events, busiest, lo, hi),
                  "idle_gaps": trace.idle_gaps(events, busiest, lo, hi)}
-    log(f"trace: {n} blocks in {(hi - lo) * 1e-9:.4f} s; busy "
+    log(f"trace: {ctx.n_blocks} blocks in {(hi - lo) * 1e-9:.4f} s; busy "
         f"{[round(x, 4) for x in busy]} s on {devices}")
     log(f"trace: top device ops {breakdown['device_ops']}")
     return (metrics, {"busy_s": float(np.mean(busy)),
                       "window_s": (hi - lo) * 1e-9}, breakdown)
 
 
-def _check(cell, cfg, k_w, gen, actives, prints, changes, *,
+def _check(cell, cfg, layout, k_w, gen, actives, prints, changes, *,
            control: bool = False) -> tuple[dict, dict | None, dict]:
     """Run the plain reference over the checked blocks and compare.  With
     ``control``, also put the reference computed in fp8 in the program's
@@ -392,7 +474,7 @@ def _check(cell, cfg, k_w, gen, actives, prints, changes, *,
     ref_mod = _load_module(_code(cell, "reference", cell.config["reference"]),
                            "bench_reference_" + cell.config["reference"])
     K = cell.traffic["agents"]
-    w0 = jax.jit(lambda k: model.make_weights(k, cfg))(k_w)
+    w0 = jax.jit(lambda k: layout.make_weights(k, cfg))(k_w)
 
     def follow(precision):
         ref = check.Reference(ref_mod.loss, cell.config["model"],
@@ -423,8 +505,11 @@ def _check(cell, cfg, k_w, gen, actives, prints, changes, *,
               f"(first block), {names[iN[0]]} agent {iN[1]} (block "
               f"{len(actives)}); {int((~keep).sum())} leaves left out",
               file=sys.stderr, flush=True)
+        gM = check.median_leaf_gap(got[-1], ref_changes[-1], keep)
         return {"change1_gap": {"value": g1, "limit": lim["change1_gap"]},
-                "changeN_gap": {"value": gN, "limit": lim["changeN_gap"]}}
+                "changeN_gap": {"value": gN, "limit": lim["changeN_gap"]},
+                "changeN_median_gap": {"value": gM,
+                                       "limit": lim["changeN_median_gap"]}}
 
     checks = gaps(changes, "check")
     checks["inactive_moved"] = {"value": moved,

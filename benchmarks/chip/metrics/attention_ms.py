@@ -6,4 +6,4 @@ from benchmarks.chip import scopes
 
 
 def read(ctx):
-    return scopes.per_block_ms(ctx, lambda s, d: s.attention[d])
+    return scopes.scope_ms(ctx, scopes.ATTENTION)

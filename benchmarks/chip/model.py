@@ -8,11 +8,12 @@ normal sharded engine around it, rematerialising every layer as the
 published-width configurations do.
 
 Weights and token blocks are the benchmark's own, made on the device from
-the seed: :func:`make_weights` draws one agent's weights in a canonical
-layout (one array per kind of tensor, stacked over layers), which the
-plain reference reads directly and :func:`to_program` renames into the
-program's parameter tree.  Every agent starts from the same weights, as a
-deployment broadcasts one initial model.
+the seed.  The weights' layout belongs to the configuration's family, whose
+module (``layouts/<reference>.py``) draws one agent's weights in the
+layout the plain reference reads and renames them into the program's
+parameter tree; :func:`check_program_layout` holds the two trees to each
+other.  Every agent starts from the same weights, as a deployment
+broadcasts one initial model.  :func:`make_block` draws the token stream.
 """
 from __future__ import annotations
 
@@ -21,11 +22,9 @@ import json
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 __all__ = ["load_json", "model_config", "register_model", "seed_key",
-           "make_weights", "to_program", "from_program", "make_block",
-           "check_program_layout"]
+           "make_block", "check_program_layout"]
 
 
 def load_json(path) -> dict:
@@ -80,81 +79,15 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def _normal(key, shape, scale):
-    return jax.random.normal(key, shape, jnp.float32) * scale
-
-
-def make_weights(key: jax.Array, cfg) -> dict:
-    """One agent's weights in the canonical layout, in the configuration's
-    dtype.  Scales follow the program's initialiser (embedding 0.02,
-    projections 1/sqrt(fan-in) of d_model, the down projection 1/sqrt(d_ff),
-    norms 1), so the benchmark trains what the program would."""
-    D, F, V, L = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.num_layers
-    Hq = cfg.num_heads * cfg.head_dim
-    Hkv = cfg.num_kv_heads * cfg.head_dim
-    dt = jnp.dtype(cfg.dtype)
-    ks = jax.random.split(key, 9)
-    s = 1.0 / np.sqrt(D)
-    w = {
-        "embed": _normal(ks[0], (V, D), 0.02).astype(dt),
-        "layers": {
-            "ln1": jnp.ones((L, D), dt),
-            "wq": _normal(ks[1], (L, D, Hq), s).astype(dt),
-            "wk": _normal(ks[2], (L, D, Hkv), s).astype(dt),
-            "wv": _normal(ks[3], (L, D, Hkv), s).astype(dt),
-            "wo": _normal(ks[4], (L, Hq, D), s).astype(dt),
-            "ln2": jnp.ones((L, D), dt),
-            "w_gate": _normal(ks[5], (L, D, F), s).astype(dt),
-            "w_up": _normal(ks[6], (L, D, F), s).astype(dt),
-            "w_down": _normal(ks[7], (L, F, D), 1.0 / np.sqrt(F)).astype(dt),
-        },
-        "final_norm": jnp.ones((D,), dt),
-    }
-    if not cfg.tie_embeddings:
-        w["lm_head"] = _normal(ks[8], (D, V), s).astype(dt)
-    return w
-
-
-def to_program(w: dict, cfg) -> dict:
-    """The canonical weights renamed into the program's parameter tree
-    (``repro.models.transformer``: one scanned segment of attention
-    blocks).  Leading axes, such as the agent axis, pass through."""
-    if cfg.family != "dense" or cfg.mlp_act != "silu" or cfg.qk_norm:
-        raise ValueError("the benchmark's weights cover dense SwiGLU "
-                         f"decoders without qk-norm, not {cfg.name}")
-    lw = w["layers"]
-    seg = {"ln1": {"scale": lw["ln1"]},
-           "attn": {k: lw[k] for k in ("wq", "wk", "wv", "wo")},
-           "ln2": {"scale": lw["ln2"]},
-           "mlp": {k: lw[k] for k in ("w_gate", "w_up", "w_down")}}
-    p = {"embed": w["embed"],
-         "segments": {f"00.attn.{cfg.num_layers:03d}": seg},
-         "final_norm": {"scale": w["final_norm"]}}
-    if "lm_head" in w:
-        p["lm_head"] = w["lm_head"]
-    return p
-
-
-def from_program(p: dict) -> dict:
-    """The inverse of :func:`to_program`."""
-    (seg,) = p["segments"].values()
-    w = {"embed": p["embed"],
-         "layers": {"ln1": seg["ln1"]["scale"], **seg["attn"],
-                    "ln2": seg["ln2"]["scale"], **seg["mlp"]},
-         "final_norm": p["final_norm"]["scale"]}
-    if "lm_head" in p:
-        w["lm_head"] = p["lm_head"]
-    return w
-
-
-def check_program_layout(cfg) -> None:
-    """Fail loudly when the program's parameter tree no longer matches
-    :func:`to_program` (names, shapes or dtypes)."""
+def check_program_layout(cfg, layout) -> None:
+    """Fail loudly when the program's parameter tree no longer matches the
+    family module ``layout``'s ``to_program`` (names, shapes or dtypes)."""
     from repro.models import transformer as tf
     want = jax.eval_shape(lambda k: tf.init_params(k, cfg),
                           jax.random.PRNGKey(0))
-    have = jax.eval_shape(lambda k: to_program(make_weights(k, cfg), cfg),
-                          jax.random.PRNGKey(0))
+    have = jax.eval_shape(
+        lambda k: layout.to_program(layout.make_weights(k, cfg), cfg),
+        jax.random.PRNGKey(0))
     if jax.tree.structure(want) != jax.tree.structure(have) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype)
             for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(have))):
@@ -164,10 +97,26 @@ def check_program_layout(cfg) -> None:
 
 def make_block(key: jax.Array, index, traffic: dict, vocab_size: int) -> dict:
     """Block ``index`` of the training stream: for each of T local steps
-    and K agents, ``batch`` sequences of ``seq`` next-token pairs drawn
-    uniformly from the vocabulary.  Every row of every block differs."""
+    and K agents, ``batch`` sequences of ``seq`` next-token pairs.  Every
+    row of every block differs.
+
+    Ids are uniform over the vocabulary, unless the traffic gives
+    ``"token_zipf": s`` (s > 0): then id r is drawn with probability
+    proportional to (r + 1)^-s, as the ranks of real text fall, by the
+    inverse of one (V,) float32 distribution function."""
     T, K = traffic["local_steps"], traffic["agents"]
     B, S = traffic["batch"], traffic["seq"]
-    toks = jax.random.randint(jax.random.fold_in(key, index),
-                              (T, K, B, S + 1), 0, vocab_size, jnp.int32)
+    shape = (T, K, B, S + 1)
+    k = jax.random.fold_in(key, index)
+    if "token_zipf" in traffic:
+        s = float(traffic["token_zipf"])
+        if not s > 0:
+            raise ValueError(f"token_zipf must be above 0, not {s}")
+        cdf = jnp.cumsum(jnp.arange(1, vocab_size + 1, dtype=jnp.float32)
+                         ** -s)
+        u = jax.random.uniform(k, shape, jnp.float32) * cdf[-1]
+        toks = jnp.minimum(jnp.searchsorted(cdf, u, side="right"),
+                           vocab_size - 1).astype(jnp.int32)
+    else:
+        toks = jax.random.randint(k, shape, 0, vocab_size, jnp.int32)
     return {"tokens": toks[..., :-1], "labels": toks[..., 1:]}

@@ -1,7 +1,7 @@
 """Plain reference of a dense decoder's training loss, in float32.
 
 Independent of the program: it reads the benchmark's canonical weights
-(``model.make_weights``) and a configuration file's ``"model"`` sizes, and
+(``layouts/dense_lm.py``) and a configuration file's ``"model"`` sizes, and
 nothing else.  Pre-norm decoder layers: RMSNorm, grouped-query causal
 attention with rotary position embedding on the first ``rotary_pct`` of
 each head (adjacent pairs rotated together), a SwiGLU feed-forward, a

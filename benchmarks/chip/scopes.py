@@ -24,24 +24,22 @@ programs, and the window also runs the data generator's program, so only
 operations inside the block step's own executions count
 (:func:`step_ops`); a metric is their time per execution, one a block.
 
-The readers get the harness's ``ctx``.  :func:`step_split` adds the scope
-map to it as ``ctx.step_hlo`` the first time a reader asks, from the block
-step that ``harness._run`` compiled (a ``ctx`` made elsewhere, as in the
-tests, sets the field itself), and caches the split as ``ctx.step_split``.
-Where neither gives a map, or the map names none of these scopes (a
-program without them), every reader returns None.
+The readers get the harness's ``ctx``, whose ``step_hlo`` is the scope map
+of the block step the run compiled.  :func:`step_split` caches the split
+as ``ctx.step_split`` the first time a reader asks.  Where there is no map,
+or it names none of these scopes (a program without them), every reader
+returns None.  :func:`scope_ms` gives the device time under any scope the
+program names, so a reader of a new scope is one line.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-import sys
-import time
 
 from benchmarks.chip import trace
 
-__all__ = ["StepHlo", "parse_hlo", "bucket", "BUCKETS",
-           "in_attention", "step_ops", "step_split", "per_block_ms",
+__all__ = ["StepHlo", "parse_hlo", "bucket", "BUCKETS", "scope_names",
+           "step_ops", "Split", "step_split", "per_block_ms", "scope_ms",
            "SAMPLE", "LOCAL_UPDATE", "APPLY", "ATTENTION", "COMBINE",
            "FLATTEN", "UNFLATTEN", "JVP", "TRANSPOSE", "REMAT"]
 
@@ -169,8 +167,9 @@ def bucket(op_name: str) -> str:
     return "unscoped"
 
 
-def in_attention(op_name: str) -> bool:
-    return ATTENTION in op_name.split("/")
+def scope_names(op_name: str) -> set:
+    """The scopes an ``op_name`` lies under: each component of its path."""
+    return {p for p in op_name.split("/") if p}
 
 
 def step_ops(events, hlo: StepHlo, lo: int, hi: int) -> dict:
@@ -218,12 +217,14 @@ def step_ops(events, hlo: StepHlo, lo: int, hi: int) -> dict:
 
 @dataclasses.dataclass
 class Split:
-    """Device time (ns) of the block step on each device: by bucket, in the
-    attention scope, and by operation family for the unscoped operations
-    and for those whose ``op_name`` is inherited; with the number of the
-    step's executions it sums over."""
+    """Device time (ns) of the block step on each device: by bucket, under
+    each named scope (``scopes``: every component of the operations'
+    ``op_name``, so one operation counts under each scope around it), and
+    by operation family for the unscoped operations and for those whose
+    ``op_name`` is inherited; with the number of the step's executions it
+    sums over."""
     buckets: dict
-    attention: dict
+    scopes: dict
     unscoped: dict
     inherited: dict
     runs: dict
@@ -232,40 +233,27 @@ class Split:
 def _split(events, hlo: StepHlo, lo: int, hi: int) -> Split:
     split = Split({}, {}, {}, {}, {})
     for dev, runs in step_ops(events, hlo, lo, hi).items():
-        t = dict.fromkeys(BUCKETS, 0)
-        att, unscoped, inherited = 0, {}, {}
+        per_op: dict = {}
         for name, a, b in (op for run in runs for op in run):
-            if trace._WRAPPERS.match(name):
-                continue
+            if not trace._WRAPPERS.match(name):
+                per_op[name] = per_op.get(name, 0) + (b - a)
+        t = dict.fromkeys(BUCKETS, 0)
+        under, unscoped, inherited = {}, {}, {}
+        for name, ns in per_op.items():
             op = hlo.op_names[name]
             k = bucket(op)
-            t[k] += b - a
-            if in_attention(op):
-                att += b - a
+            t[k] += ns
+            for scope in scope_names(op):
+                under[scope] = under.get(scope, 0) + ns
             for fam, hit in ((unscoped, k == "unscoped"),
                              (inherited, name in hlo.inherited)):
                 if hit:
                     f = trace._family(name)
-                    fam[f] = fam.get(f, 0) + (b - a)
-        split.buckets[dev], split.attention[dev] = t, att
+                    fam[f] = fam.get(f, 0) + ns
+        split.buckets[dev], split.scopes[dev] = t, under
         split.unscoped[dev], split.inherited[dev] = unscoped, inherited
         split.runs[dev] = len(runs)
     return split
-
-
-def _compiled_block_step():
-    """The block step compiled by the ``harness._run`` that is calling the
-    readers (its local ``step``), or None outside such a run.  The
-    harness's ``ctx`` has no field for the program, and the harness is the
-    benchmark's accepted code, so the readers look it up where it lives."""
-    f = sys._getframe(1)
-    while f is not None:
-        if (f.f_code.co_name == "_run" and f.f_globals.get("__name__")
-                == "benchmarks.chip.harness"):
-            step = f.f_locals.get("step")
-            return step if hasattr(step, "as_text") else None
-        f = f.f_back
-    return None
 
 
 def _note(ctx, split: Split, dev: str) -> None:
@@ -284,7 +272,7 @@ def _note(ctx, split: Split, dev: str) -> None:
     shares = ", ".join(f"{k} {pct(v)}" for k, v in t.items())
     ctx.note(f"block step split on {dev}: {split.runs[dev]} executions, "
              f"{1e-6 * total / split.runs[dev]:.3f} ms each; {shares}; "
-             f"attention {pct(split.attention[dev])} "
+             f"attention {pct(split.scopes[dev].get(ATTENTION, 0))} "
              f"(overlaps); scoped {pct(total - t['unscoped'])}; largest "
              f"unscoped families: {top(split.unscoped[dev])}; without "
              f"metadata of their own "
@@ -296,19 +284,11 @@ def step_split(ctx) -> Split | None:
     """The block step's split of ``ctx``'s trace, computed once per
     ``ctx``; None where there is no scope map, or it names no scope."""
     if not hasattr(ctx, "step_split"):
-        if not hasattr(ctx, "step_hlo"):
-            step = _compiled_block_step()
-            t0 = time.perf_counter()
-            ctx.step_hlo = None if step is None else parse_hlo(step.as_text())
-            if ctx.step_hlo is not None:
-                ctx.note(f"scope map of the block step: "
-                         f"{len(ctx.step_hlo.op_names)} instructions in "
-                         f"{time.perf_counter() - t0:.3f} s")
+        hlo = getattr(ctx, "step_hlo", None)
         split = None
-        if ctx.step_hlo is not None and any(
-                bucket(op) != "unscoped" for op in
-                ctx.step_hlo.op_names.values()):
-            split = _split(ctx.events, ctx.step_hlo, ctx.lo, ctx.hi)
+        if hlo is not None and any(bucket(op) != "unscoped"
+                                   for op in hlo.op_names.values()):
+            split = _split(ctx.events, hlo, ctx.lo, ctx.hi)
             busiest = max(split.buckets,
                           key=lambda d: sum(split.buckets[d].values()))
             _note(ctx, split, busiest)
@@ -326,3 +306,9 @@ def per_block_ms(ctx, of) -> float | None:
     ms = max((1e-6 * of(split, d) / split.runs[d] for d in split.runs
               if split.runs[d]), default=0.0)
     return ms or None
+
+
+def scope_ms(ctx, name: str) -> float | None:
+    """Device time per block under the scope ``name``, in ms, on the device
+    with the most; None as :func:`per_block_ms`."""
+    return per_block_ms(ctx, lambda s, d: s.scopes[d].get(name, 0))

@@ -40,6 +40,29 @@ def test_an_agent_that_moved_where_the_reference_did_not():
     assert check.worst_leaf_gap(still, still, np.ones(1, bool))[0] == 0.0
 
 
+def test_median_leaf_gap_does_not_follow_one_leaf():
+    ref = _sq([[1.0, 2.0], [2.0, 2.0], [3.0, 2.0], [0.01, 0.01]])
+    keep = np.ones(4, bool)
+    assert check.median_leaf_gap(ref, ref, keep) == 0.0
+    # one small leaf moved ten times as far: the worst leaf follows it
+    noisy = ref.copy()
+    noisy[3, 0] = _sq(0.1)
+    assert check.worst_leaf_gap(noisy, ref, keep)[0] == pytest.approx(0.045)
+    assert check.median_leaf_gap(noisy, ref, keep) == 0.0
+    # every leaf of agent 1 moved 10% further: the median reads its gap
+    # (leaf 3 over the median live norm, 2.0: 0.001 / 2)
+    wide = ref.copy()
+    wide[:, 1] *= 1.1 ** 2
+    assert check.median_leaf_gap(wide, ref, keep) == pytest.approx(
+        np.median([0.1, 0.1, 0.1, 0.0005]))
+    # a leaf left out counts in no agent's median
+    noisy[:3, 1] = wide[:3, 1]
+    assert check.median_leaf_gap(noisy, ref, keep) == pytest.approx(0.1)
+    assert check.median_leaf_gap(noisy, ref, np.array([1, 1, 1, 0], bool)) == (
+        pytest.approx(0.1))
+    assert check.median_leaf_gap(noisy, ref, np.array([0, 0, 0, 1], bool)) > 1
+
+
 def test_leaves_left_out_by_the_first_block_that_moved():
     first = _sq([[0.0, 0.0]] * 3)
     later = _sq([[1.0, 1.0], [1e-4, 0.0], [2.0, 0.5]])

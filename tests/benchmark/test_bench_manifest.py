@@ -59,7 +59,8 @@ def test_every_file_of_a_cell_is_found_by_name(cell):
     assert c.per_layer, "every cell reports a per-layer metric"
     for m in c.per_layer:
         assert harness._code(c, "metrics", m["name"]).exists()
-    assert set(c.limits) == {"change1_gap", "changeN_gap", "inactive_moved"}
+    assert set(c.limits) == {"change1_gap", "changeN_gap",
+                             "changeN_median_gap", "inactive_moved"}
 
 
 def test_config_files_are_unique_and_under_paths():

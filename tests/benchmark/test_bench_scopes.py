@@ -33,8 +33,9 @@ def tiny_step_hlo(fixtures):
     cfg = model.model_config(cell.config)
     eng, _ = harness._build(cell, cfg, model.register_model(cfg), 7,
                             jax.devices()[:1])
-    params = model.to_program(check.broadcast_agents(
-        model.make_weights(jax.random.PRNGKey(0), cfg),
+    layout = harness.load_layout(cell)
+    params = layout.to_program(check.broadcast_agents(
+        layout.make_weights(jax.random.PRNGKey(0), cfg),
         cell.traffic["agents"]), cfg)
     state = eng.init_state(params, eng.optimizer.init(params),
                            key=jax.random.PRNGKey(1))
@@ -226,6 +227,16 @@ def test_readers_on_the_synthetic_trace(metric, ms):
     assert _reader(metric)(ctx) == pytest.approx(ms)
 
 
+@pytest.mark.parametrize("scope,ms", [
+    ("attention", 2 * (3 + 4) * 1e-3), ("apply", 2 * 2e-3),
+    ("combine", (5 + 20 + 3) * 1e-3), ("flatten", 5e-3),
+    ("checkpoint", 2 * (6 + 4) * 1e-3), ("no_such_scope", None),
+])
+def test_scope_ms_reads_any_named_scope(scope, ms):
+    got = scopes.scope_ms(_ctx(_synthetic(), step_hlo=HLO), scope)
+    assert got == (None if ms is None else pytest.approx(ms))
+
+
 def test_split_is_noted_once_with_its_coverage():
     ctx = _ctx(_synthetic(), step_hlo=HLO)
     for m in READERS:
@@ -249,16 +260,21 @@ def test_readers_give_none_without_a_scope_map(metric):
 
 def test_readers_take_the_step_the_harness_ran(fixtures, monkeypatch,
                                                tmp_path):
-    """Through ``harness.run`` with the profiler on, the readers find the
-    block step the harness compiled.  A CPU trace has no device plane, so
-    each block gets one 10 ns device operation per instruction of that
-    step: its entry computation in order, with every other instruction
-    inside its first loop."""
+    """Through ``harness.run`` with the profiler on, the readers get the
+    scope map of the block step the harness compiled, in ``ctx``.  A CPU
+    trace has no device plane, so each block gets one 10 ns device
+    operation per instruction of that step: its entry computation in
+    order, with every other instruction inside its first loop."""
     import dataclasses
     import time
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     load, events = harness.load_cell, trace.events_from_xplane
+    parse, parsed = scopes.parse_hlo, []
     counts = {}
+
+    def parse_hlo(text):
+        parsed.append(parse(text))
+        return parsed[-1]
 
     def load_cell(*a, **kw):
         cell = load(*a, **kw)
@@ -269,7 +285,7 @@ def test_readers_take_the_step_the_harness_ran(fixtures, monkeypatch,
 
     def with_step_ops(prof_dir):
         ev = events(prof_dir)
-        hlo = scopes.parse_hlo(scopes._compiled_block_step().as_text())
+        (hlo,) = parsed
         entry = set(hlo.entry)
         loop = next(n for n in hlo.entry if trace._WRAPPERS.match(n))
         body = [n for n in hlo.op_names if n not in entry
@@ -277,8 +293,9 @@ def test_readers_take_the_step_the_harness_ran(fixtures, monkeypatch,
         for n in [n for n in hlo.entry if n != loop] + body:
             k = scopes.bucket(hlo.op_names[n])
             counts[k] = counts.get(k, 0) + 1
-            counts["attention"] = (counts.get("attention", 0)
-                                   + scopes.in_attention(hlo.op_names[n]))
+            counts["attention"] = (
+                counts.get("attention", 0)
+                + (scopes.ATTENTION in scopes.scope_names(hlo.op_names[n])))
         for _, _, s, _ in [e for e in ev if e[0] == H
                            and e[1] == "bench.block"]:
             t = s + 10
@@ -294,6 +311,7 @@ def test_readers_take_the_step_the_harness_ran(fixtures, monkeypatch,
         return ev
 
     monkeypatch.setattr(harness, "load_cell", load_cell)
+    monkeypatch.setattr(scopes, "parse_hlo", parse_hlo)
     monkeypatch.setattr(trace, "events_from_xplane", with_step_ops)
     out = harness.run("tiny.t2", 5, 0.0, True, t_start=time.perf_counter(),
                       manifest_path=fixtures / "BENCHMARK.json",
